@@ -186,37 +186,21 @@ def bench_scaling(arch_id: str, *, smoke: bool, slots: int, requests: int,
     ``adopt_compiled``. The timed fleet replays the warmed trace, so the
     rows record steady-state routing + decode, not compile time.
 
-    Runs WITHOUT recorders: the obs JitProfiler pins AOT executables to the
-    lowering device, while plain ``jax.jit`` caches one executable per
-    device — exactly what a fleet spread over devices needs. The modeled
-    aggregate (``agg_tokens_per_s``, see RouterStats.aggregate) charges the
-    slowest replica's busy wall plus router overhead, since in-process
-    replicas step serially rather than concurrently."""
+    Runs WITHOUT recorders, so the timed replays carry no profiling. The
+    modeled aggregate (``agg_tokens_per_s``, see RouterStats.aggregate)
+    charges the slowest replica's busy wall plus router overhead, since
+    in-process replicas step serially rather than concurrently."""
     import jax
     from repro.configs import get_arch
     from repro.models import transformer as tfm
-    from repro.serve.engine import Engine, synth_trace
+    from repro.serve.engine import make_replicas, synth_trace
     from repro.serve.router import Router
 
     arch = get_arch(arch_id, smoke=smoke)
     m = arch.model
     params = tfm.init_model(jax.random.PRNGKey(seed), m)
-    max_len = prompt_len + new_tokens
-    page_kw = dict(page_size=page_size or None)
-    devices = jax.devices()
-
-    def fleet(n, adopt_from=None):
-        eng0 = Engine(params, m, n_slots=slots, max_len=max_len,
-                      device=devices[0], **page_kw)
-        if adopt_from is not None:
-            eng0.adopt_compiled(adopt_from)
-        reps = [eng0]
-        for i in range(1, n):
-            reps.append(Engine(eng0.params, m, n_slots=slots,
-                               max_len=max_len,
-                               device=devices[i % len(devices)],
-                               **page_kw).adopt_compiled(eng0))
-        return reps
+    geometry = dict(n_slots=slots, max_len=prompt_len + new_tokens,
+                    page_size=page_size or None)
 
     rows, warm_src = [], None
     for n in replica_counts:
@@ -236,7 +220,7 @@ def bench_scaling(arch_id: str, *, smoke: bool, slots: int, requests: int,
             r.arrival = (i // n) * stagger
         # warm fleet pays any per-device compiles; the shared jit callables
         # then hold one cached executable per device for the timed fleet
-        warm = fleet(n, adopt_from=warm_src)
+        warm = make_replicas(params, m, n, adopt_from=warm_src, **geometry)
         Router(warm).run(list(reqs))
         warm_src = warm_src or warm[0]
         # best-of-3: busy walls are tens of ms at smoke scale, so a single
@@ -244,7 +228,8 @@ def bench_scaling(arch_id: str, *, smoke: bool, slots: int, requests: int,
         # efficiency; the best replay is the steady-state measurement
         rep = None
         for _ in range(3):
-            timed = Router(fleet(n, adopt_from=warm_src))
+            timed = Router(make_replicas(params, m, n, adopt_from=warm_src,
+                                         **geometry))
             timed.run(list(reqs))
             r = timed.report()
             if rep is None or r["agg_tokens_per_s"] > rep["agg_tokens_per_s"]:
@@ -306,6 +291,8 @@ def main(argv=None) -> None:
     archs = args.arch or DEFAULT_ARCHS
 
     import jax
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     # (arch, label, page_size) cells; the optional monolithic twin reruns
     # the first arch on the identical trace with the one-page-per-slot

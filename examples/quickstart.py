@@ -48,7 +48,7 @@ print(f"deployed-ref vs deployed-lut err: "
       f"{float(jnp.abs(y_ref - y_q).max()):.4f} (input quantization only)")
 print(f"deployed-lut vs fused Pallas kernel err: "
       f"{float(jnp.abs(y_q - y_f).max()):.2e} "
-      f"(same frozen artifact, bit-compatible — pinned in "
+      f"(same frozen artifact, a few ulp apart — pinned in "
       "tests/test_kan_backends.py)")
 
 # CIM crossbar backend with/without KAN-SAM: same deploy/apply contract

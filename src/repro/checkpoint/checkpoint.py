@@ -26,9 +26,6 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import numpy as np
 
-from repro.dist import compat as _compat  # noqa: F401  (jax<0.5 mesh API:
-# elastic restore targets are built with jax.make_mesh(..., axis_types=...))
-
 PyTree = Any
 
 

@@ -47,6 +47,7 @@ from typing import Any, Dict, Iterator, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.extend import core as jex_core
 
 from repro.core import quant, splines
 from repro.core.quant import ASPConfig
@@ -364,7 +365,9 @@ class RefBackend(KANBackend):
 @register_backend("lut")
 class LutBackend(KANBackend):
     """ASP-KAN-HAQ quantized expanded-basis matmul (the paper-faithful ACIM
-    dataflow on the MXU; the serving default). Bit-compatible with fused."""
+    dataflow on the MXU; the serving default). Same basis values as fused;
+    the two sum the contraction in a different order, so they agree to a
+    few ulp, not bitwise."""
 
     def run(self, layer, lspec, spec, x, rng=None):
         """f32 expanded-basis matmul over the int8 codes + one scale."""
@@ -658,9 +661,9 @@ def _iter_eqns(jaxpr) -> Iterator:
         for v in eqn.params.values():
             vs = v if isinstance(v, (list, tuple)) else (v,)
             for sub in vs:
-                if isinstance(sub, jax.core.ClosedJaxpr):
+                if isinstance(sub, jex_core.ClosedJaxpr):
                     yield from _iter_eqns(sub.jaxpr)
-                elif isinstance(sub, jax.core.Jaxpr):
+                elif isinstance(sub, jex_core.Jaxpr):
                     yield from _iter_eqns(sub)
 
 

@@ -1,11 +1,7 @@
 """repro.dist — distributed execution: logical-axis sharding rules
 (``sharding``), int8 error-feedback gradient all-reduce (``compress``) and
 preemption / straggler handling (``fault``).
-
-Importing this package also installs the jax<0.5 mesh-API compat shim
-(``compat``) so ``jax.make_mesh(..., axis_types=...)`` works everywhere.
 """
-from repro.dist import compat as _compat  # noqa: F401  (installs on import)
 from repro.dist import compress, fault, sharding
 from repro.dist.sharding import (RULES, current_mesh, named_sharding,
                                  override_rules, shard, spec_for,

@@ -27,8 +27,6 @@ import contextlib
 import threading
 from typing import Any, Dict, Optional, Sequence, Tuple
 
-from repro.dist import compat as _compat  # noqa: F401  (jax<0.5 mesh API)
-
 import jax
 from jax.interpreters import pxla
 from jax.sharding import NamedSharding, PartitionSpec as P

@@ -1,14 +1,2 @@
-# OPTIONAL layer. Add <name>.py (or .cu) + ops.py + ref.py ONLY
-# for compute hot-spots the paper itself optimizes with a custom
-# kernel. Leave this package empty if the paper has none.
-
-# jax<0.5 pallas compat: the kernels target the renamed CompilerParams API.
-# Guarded so CPU-only consumers of the reference impls survive a jax where
-# the TPU pallas import itself fails.
-try:
-    from jax.experimental.pallas import tpu as _pltpu
-except ImportError:  # pragma: no cover
-    pass
-else:
-    if not hasattr(_pltpu, "CompilerParams"):
-        _pltpu.CompilerParams = _pltpu.TPUCompilerParams
+"""Pallas TPU kernels (``kan_fused``, ``cim_mac``, ``ssd_scan``), their
+jitted padding wrappers (``ops``) and pure-jnp oracles (``ref``)."""

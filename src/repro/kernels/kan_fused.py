@@ -6,17 +6,20 @@ implementation materializes ``E`` in HBM — a (G+K)× activation blow-up that
 makes the layer memory-bound. This kernel fuses the whole chain in VMEM:
 
     x  ──quantize──► q ──PowerGap──► (seg = q >> LD, loc = q & (L-1))
-       ──SH-LUT (one-hot MXU gather, hemi + reflection)──► K+1 taps
-       ──local→global routing (iota compare-add == the paper's DEMUX)──► E tile
-       ──MXU──► acc += E_tile @ dequant(C_tile)
+       ──SH-LUT (select chain over the hemi rows + reflection)──► K+1 taps
+       ──local→global routing (compare-select == the paper's DEMUX)──► E_j
+       ──MXU──► acc += Σ_j E_j @ dequant(C_j)
 
 ``E`` never leaves VMEM; coefficients are stored int8 in HBM (the paper's
 8-bit ci') and dequantized in registers, cutting weight traffic 2× vs bf16.
 
 Tiling: grid = (B/bm, O/bo, I/bi), contraction over the I axis innermost with
-an f32 VMEM accumulator; C blocks are [bi, S, bo] (S = G+K) reshaped in-VMEM
-to [bi*S, bo] so the MXU contraction dim is bi*S (pick bi so bi*S is a
-multiple of 128; e.g. S=8 → bi=16, S=67 → padding handled in ops.py).
+an f32 VMEM accumulator. Coefficients are laid out slot-major [S, I, O]
+(S = G+K), so a C block [S, bi, bo] holds S aligned [bi, bo] planes and the
+tile's contraction is S matmuls ``E_j @ C_j`` of the per-slot basis planes
+``E_j [bm, bi]``: every value keeps the [bm, bi] layout of the x tile, and
+nothing is reshaped in VMEM. bi is a multiple of 128 or the whole (padded) I
+and bo a multiple of 128 (ops.py picks them and pads).
 """
 from __future__ import annotations
 
@@ -33,7 +36,7 @@ from repro.core.quant import ASPConfig
 Array = jax.Array
 
 
-def _kan_fused_kernel(x_ref, c_ref, scale_ref, hemi2_ref, out_ref, acc_ref, *,
+def _kan_fused_kernel(x_ref, c_ref, scale_ref, hemi_ref, out_ref, acc_ref, *,
                       asp: ASPConfig, n_i_blocks: int):
     """One (bm × bo) output tile; grid dim 2 walks the I contraction."""
     i_blk = pl.program_id(2)
@@ -44,46 +47,42 @@ def _kan_fused_kernel(x_ref, c_ref, scale_ref, hemi2_ref, out_ref, acc_ref, *,
 
     k1 = asp.n_taps                       # K+1
     s = asp.n_basis                       # G+K
-    ld = asp.ld
     lvl = asp.levels_per_interval         # L = 2^LD
-    half = hemi2_ref.shape[0]             # ceil(L/2)
+    half = hemi_ref.shape[0]              # ceil(L/2)
 
     x = x_ref[...].astype(jnp.float32)    # [bm, bi]
-    bm, bi = x.shape
-    n = bm * bi
 
     # --- quantize (ASP-KAN-HAQ aligned grid) ---
     q = jnp.floor((x - asp.x_min) / asp.step)
     q = jnp.clip(q, 0, asp.n_levels - 1).astype(jnp.int32)
 
     # --- PowerGap decode: global segment via shift, local via mask ---
-    seg = jax.lax.shift_right_logical(q, ld).reshape(n, 1)        # [n,1]
-    loc = jax.lax.bitwise_and(q, lvl - 1).reshape(n, 1)           # [n,1]
+    seg = jax.lax.shift_right_logical(q, asp.ld)
+    loc = jax.lax.bitwise_and(q, lvl - 1)
 
-    # --- SH-LUT lookup: one-hot MXU gather from the hemi table.
-    # hemi2 = concat(hemi, reverse(hemi, axis=1), axis=1): [half, 2*(K+1)],
-    # so reflection selects the pre-reversed tap block (no in-kernel flip).
+    # --- SH-LUT lookup: select chain over the hemi rows (SMEM scalars).
+    # Reflection (loc >= half) reads row L-1-loc with the taps reversed.
     refl = loc >= half
-    idx = jnp.where(refl, lvl - 1 - loc, loc)                      # [n,1]
-    iota_h = jax.lax.broadcasted_iota(jnp.int32, (n, half), 1)
-    onehot = (iota_h == idx).astype(jnp.float32)
-    taps_pair = jax.lax.dot(onehot, hemi2_ref[...].astype(jnp.float32),
-                            preferred_element_type=jnp.float32)    # [n, 2K+2]
-    taps = jnp.where(refl, taps_pair[:, k1:], taps_pair[:, :k1])   # [n, K+1]
+    idx = jnp.where(refl, lvl - 1 - loc, loc)
+    rows = [jnp.zeros_like(x) for _ in range(k1)]
+    for h in range(half):
+        hit = idx == h
+        for t in range(k1):
+            rows[t] = jnp.where(hit, hemi_ref[h, t], rows[t])
+    taps = [jnp.where(refl, rows[k1 - 1 - t], rows[t]) for t in range(k1)]
 
-    # --- local→global routing: scatter K+1 taps into the S basis slots.
-    # t = slot - segment; slot holds tap value t when 0 <= t <= K. This is
-    # the TPU form of the paper's PowerGap DEMUX (local info -> global slot).
-    iota_s = jax.lax.broadcasted_iota(jnp.int32, (n, s), 1)
-    t_idx = iota_s - seg                                           # [n, S]
-    e = jnp.zeros((n, s), dtype=jnp.float32)
-    for tap in range(k1):
-        e = e + jnp.where(t_idx == tap, taps[:, tap:tap + 1], 0.0)
-
-    # --- MXU contraction against the (dequantized-int8) coefficient tile ---
-    em = e.reshape(bm, bi * s)
-    c = c_ref[...].astype(jnp.float32).reshape(bi * s, -1)         # [bi*S, bo]
-    acc_ref[...] += jax.lax.dot(em, c, preferred_element_type=jnp.float32)
+    # --- local→global routing + MXU contraction, one basis slot at a time:
+    # slot j holds tap j - seg when 0 <= j - seg <= K (the TPU form of the
+    # paper's PowerGap DEMUX). Slot j's coefficient plane is c_ref[j].
+    acc = acc_ref[...]
+    for j in range(s):
+        e_j = jnp.zeros_like(x)
+        for t in range(k1):
+            e_j = jnp.where(seg == j - t, taps[t], e_j)
+        acc += jax.lax.dot(e_j, c_ref[j].astype(jnp.float32),
+                           precision=jax.lax.Precision.HIGHEST,
+                           preferred_element_type=jnp.float32)
+    acc_ref[...] = acc
 
     @pl.when(i_blk == n_i_blocks - 1)
     def _finalize():
@@ -97,21 +96,21 @@ def _kan_fused_kernel(x_ref, c_ref, scale_ref, hemi2_ref, out_ref, acc_ref, *,
     static_argnames=("asp", "block_b", "block_i", "block_o", "interpret",
                      "out_dtype"))
 def kan_fused(x: Array, c_codes: Array, scale: Array, hemi: Array, *,
-              asp: ASPConfig, block_b: int = 128, block_i: int = 16,
+              asp: ASPConfig, block_b: int = 128, block_i: int = 128,
               block_o: int = 128, interpret: bool = False,
               out_dtype=jnp.float32) -> Array:
     """Fused KAN spline forward.
 
-    x: [B, I] float (bounded); c_codes: [I, S, O] int8; scale: [1, O] f32;
-    hemi: [half, K+1] f32. B % block_b == 0, I % block_i == 0,
+    x: [B, I] float (bounded); c_codes: [S, I, O] int8 (slot-major, so each
+    basis slot's coefficient plane is one aligned [I, O] tile); scale:
+    [1, O] f32; hemi: [half, K+1] f32. B % block_b == 0, I % block_i == 0,
     O % block_o == 0 (ops.py pads). Returns [B, O] out_dtype.
     """
     b, i = x.shape
     o = c_codes.shape[-1]
     s = asp.n_basis
-    assert c_codes.shape == (i, s, o), (c_codes.shape, (i, s, o))
+    assert c_codes.shape == (s, i, o), (c_codes.shape, (s, i, o))
     nb, ni, no = b // block_b, i // block_i, o // block_o
-    hemi2 = jnp.concatenate([hemi, hemi[:, ::-1]], axis=1)
 
     kernel = functools.partial(_kan_fused_kernel, asp=asp, n_i_blocks=ni)
     return pl.pallas_call(
@@ -119,9 +118,9 @@ def kan_fused(x: Array, c_codes: Array, scale: Array, hemi: Array, *,
         grid=(nb, no, ni),
         in_specs=[
             pl.BlockSpec((block_b, block_i), lambda bb, oo, ii: (bb, ii)),
-            pl.BlockSpec((block_i, s, block_o), lambda bb, oo, ii: (ii, 0, oo)),
+            pl.BlockSpec((s, block_i, block_o), lambda bb, oo, ii: (0, ii, oo)),
             pl.BlockSpec((1, block_o), lambda bb, oo, ii: (0, oo)),
-            pl.BlockSpec(hemi2.shape, lambda bb, oo, ii: (0, 0)),
+            pl.BlockSpec(memory_space=pltpu.SMEM),
         ],
         out_specs=pl.BlockSpec((block_b, block_o), lambda bb, oo, ii: (bb, oo)),
         out_shape=jax.ShapeDtypeStruct((b, o), out_dtype),
@@ -129,4 +128,4 @@ def kan_fused(x: Array, c_codes: Array, scale: Array, hemi: Array, *,
         interpret=interpret,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
-    )(x, c_codes, scale, hemi2)
+    )(x, c_codes, scale, hemi.astype(jnp.float32))

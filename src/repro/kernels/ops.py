@@ -1,10 +1,10 @@
 """Jitted public wrappers around the Pallas kernels.
 
 Handles: batch-dim flattening, padding to block multiples, int8 coefficient
-quantization, interpret-mode auto-detection (CPU container → interpret=True,
-TPU → compiled), and the QAT custom-VJP (forward = quantized kernel,
-backward = straight-through float path for x, exact expanded-basis grad for
-the coefficients).
+quantization, interpret-mode selection (CPU → interpret=True, TPU →
+compiled, any other backend refused), and the QAT custom-VJP (forward =
+quantized kernel, backward = straight-through float path for x, exact
+expanded-basis grad for the coefficients).
 """
 from __future__ import annotations
 
@@ -24,7 +24,14 @@ Array = jax.Array
 
 
 def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+    """Compiled on a TPU, the Pallas interpreter on the CPU; any other
+    backend has no lowering for these kernels and is refused."""
+    backend = jax.default_backend()
+    if backend not in ("tpu", "cpu"):
+        raise RuntimeError(f"Pallas TPU kernels cannot run on backend "
+                           f"{backend!r}: only 'tpu' (compiled) and 'cpu' "
+                           "(interpret mode) are supported")
+    return backend == "cpu"
 
 
 def _round_up(x: int, m: int) -> int:
@@ -35,14 +42,27 @@ def _round_up(x: int, m: int) -> int:
 # Fused KAN spline (forward kernel + QAT custom VJP)
 # ---------------------------------------------------------------------------
 
+# Cap on one int8 coefficient block [S, bi, bo]; Pallas double-buffers it in
+# VMEM next to the f32 planes the kernel builds from it.
+_C_BLOCK_BYTES = 4 << 20
+
+
+def _lane_tile(n: int, cap: int) -> int:
+    """Tile for a dimension that is a lane dimension of some block: all of n
+    (rounded up to 8, legal as the whole padded array dim) when n <= cap,
+    else the multiple of 128 <= cap that pads n least (the larger on ties)."""
+    if n <= cap:
+        return _round_up(n, 8)
+    return min(range(cap, 127, -128), key=lambda c: _round_up(n, c))
+
+
 def _pick_blocks(b: int, i: int, o: int, s: int) -> Tuple[int, int, int]:
-    """VMEM-aware tile choice. Contraction tile bi*S targets ~256-512 lanes;
-    bm/bo target the 128×128 MXU. Small dims fall back to padded minimums."""
+    """Tiles legal for the TPU lowering: block_b is a multiple of 8, block_i
+    and block_o are multiples of 128 or the whole padded dim. The cap keeps
+    the [S, bi, bo] int8 coefficient block within ``_C_BLOCK_BYTES``."""
     block_b = min(128, _round_up(b, 8))
-    block_o = min(128, _round_up(o, 128))
-    bi = max(1, 256 // s)
-    block_i = min(_round_up(i, 8), bi)
-    return block_b, block_i, block_o
+    cap = max(128, min(512, int((_C_BLOCK_BYTES / s) ** 0.5) // 128 * 128))
+    return block_b, _lane_tile(i, cap), _lane_tile(o, cap)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
@@ -81,7 +101,8 @@ def kan_spline_fused_deployed(x: Array, codes: Array, scale: Array,
     bp, ip, op = _round_up(b, bb), _round_up(i, bi), _round_up(o, bo)
     xp = jnp.pad(xf.astype(jnp.float32),
                  ((0, bp - b), (0, ip - i)), constant_values=asp.x_min)
-    cp = jnp.pad(codes, ((0, ip - i), (0, 0), (0, op - o)))
+    cp = jnp.pad(jnp.transpose(codes, (1, 0, 2)),          # slot-major
+                 ((0, 0), (0, ip - i), (0, op - o)))
     sp = jnp.pad(scale_o, ((0, 0), (0, op - o)), constant_values=1.0)
 
     y = _kf.kan_fused(xp, cp, sp, hemi, asp=asp, block_b=bb, block_i=bi,

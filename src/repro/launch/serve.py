@@ -9,11 +9,12 @@ a host mesh (``--mesh-model N`` shards the slot pool via dist.sharding), runs
 the engine, and prints the EngineStats report.
 
 ``--replicas N`` serves the trace through ``repro.serve.router`` instead:
-N data-parallel engines share ONE deployed artifact (replica 0's params —
-KAN deploy runs once) and ``adopt_compiled`` each other so compile cost is
-paid once; the router owns the global queue, scores load/prefix-affinity
-per dispatch, and prints the RouterStats aggregate. Mutually exclusive
-with ``--mesh-model`` (a replica is whole-model by construction).
+N data-parallel engines, replica i pinned to device i (round-robin over
+``jax.devices()``), share ONE deployed artifact (replica 0's params — KAN
+deploy runs once) and ``adopt_compiled`` each other's jits; the router
+owns the global queue, scores load/prefix-affinity per dispatch, and
+prints the RouterStats aggregate. Mutually exclusive with
+``--mesh-model`` (a replica is whole-model by construction).
 ``--drain-tick T`` schedules a mid-trace drain of ``--drain-replica`` —
 its in-flight requests requeue onto the survivors and ``--check`` still
 requires full completion (the zero-lost-requests CI gate).
@@ -52,8 +53,9 @@ import json
 import jax
 
 from repro.configs import get_arch
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as tfm
-from repro.serve.engine import Engine, synth_trace
+from repro.serve.engine import Engine, make_replicas, synth_trace
 from repro.serve.scheduler import AdmissionQueue, Request
 
 
@@ -142,6 +144,7 @@ def main(argv=None):
         raise SystemExit("--drift-replica needs the router path: require "
                          "--replicas > 1 and 0 <= drift-replica < replicas")
 
+    enable_compile_cache()
     arch = get_arch(args.arch, smoke=args.smoke)
     m = arch.model
     if args.kan_backend:
@@ -193,29 +196,25 @@ def main(argv=None):
             def rec_for(i):
                 return recorder.for_replica(i) if recorder else None
 
-            eng = Engine(params, m, n_slots=args.slots, max_len=max_len,
-                         recorder=rec_for(0), **page_kw)
+            geometry = dict(n_slots=args.slots, max_len=max_len, **page_kw)
+            probe_eng = None
             eos_planted = args.check and args.new_tokens >= 3
             if eos_planted:
                 # same planted-EOS probe as the single-engine path: identical
-                # geometry, warm caches adopted by replica 0
-                probe_eng = Engine(params, m, n_slots=args.slots,
-                                   max_len=max_len, recorder=rec_for(0),
-                                   **page_kw)
+                # geometry on replica 0's device, warm caches adopted by it
+                probe_eng = Engine(params, m, device=jax.devices()[0],
+                                   recorder=rec_for(0), **geometry)
                 probe = probe_eng.run([Request(rid="probe",
                                                tokens=reqs[0].tokens,
                                                max_new=2)])
                 reqs[0].eos_id = int(probe[0].tokens[1])
-                eng.adopt_compiled(probe_eng)
-            # replicas 1..N-1 share replica 0's DEPLOYED params (KAN deploy
-            # is idempotent: one frozen artifact serves the whole fleet) and
-            # its warm jit caches (compile cost paid once)
-            replicas = [eng]
-            for i in range(1, args.replicas):
-                replicas.append(
-                    Engine(eng.params, m, n_slots=args.slots,
-                           max_len=max_len, recorder=rec_for(i),
-                           **page_kw).adopt_compiled(eng))
+            # replica i is pinned to device i (round-robin); replicas 1..N-1
+            # share replica 0's DEPLOYED params (KAN deploy is idempotent:
+            # one frozen artifact serves the whole fleet) and its jits
+            replicas = make_replicas(params, m, args.replicas,
+                                     adopt_from=probe_eng,
+                                     recorder_for=rec_for, **geometry)
+            eng = replicas[0]
             router = Router(replicas, queue=queue, recorder=recorder)
             if args.drain_tick:
                 router.schedule_drain(args.drain_replica, args.drain_tick)
@@ -254,9 +253,8 @@ def main(argv=None):
                 # deployed params, warm caches): greedy decode is
                 # deterministic, so the auto-drained fleet must emit the
                 # identical completion-token multiset
-                ref_eng = Engine(eng.params, m, n_slots=args.slots,
-                                 max_len=max_len,
-                                 **page_kw).adopt_compiled(eng)
+                ref_eng = Engine(eng.params, m, device=eng.device,
+                                 **geometry).adopt_compiled(eng)
                 ref_comps = ref_eng.run(list(reqs))
         else:
             eng = Engine(params, m, n_slots=args.slots, max_len=max_len,

@@ -28,6 +28,7 @@ from repro.checkpoint import checkpoint as ckpt
 from repro.configs import get_arch
 from repro.data import lm_synth
 from repro.dist import fault
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_host_mesh, make_production_mesh
 from repro.models import transformer as tfm
 from repro.optim import make_optimizer, warmup_cosine
@@ -52,6 +53,7 @@ def main(argv=None):
                          "path dispatches through the same core.kan "
                          "registry as serving)")
     args = ap.parse_args(argv)
+    enable_compile_cache()
 
     arch = get_arch(args.arch, smoke=args.smoke)
     m = arch.model

@@ -26,7 +26,6 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.dist.sharding import current_mesh
@@ -231,12 +230,12 @@ def apply_moe(params: Dict[str, Array], x: Array, cfg: MoEConfig, *,
             aux = {k: jax.lax.pmean(v, "model") for k, v in aux.items()}
             return y.reshape(xb.shape[0], s, d).astype(x.dtype), aux
 
-        y, aux = shard_map(
+        y, aux = jax.shard_map(
             shard_fn, mesh=mesh,
             in_specs=(P(batch_axes, None, None), P(None, None),
                       P("model"), P("model"), P("model")),
             out_specs=(P(batch_axes, None, None), P()),
-            check_rep=False,
+            check_vma=False,
         )(x, params["router"], params["wi"], params["wg"], params["wo"])
 
     if cfg.n_shared_experts:
@@ -287,14 +286,14 @@ def _apply_moe_stationary(params, x: Array, cfg: MoEConfig, mesh,
 
     ff_axis = data_axes if len(data_axes) > 1 else (data_axes[0]
                                                     if data_axes else None)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         shard_fn, mesh=mesh,
         in_specs=(P(None, None, None), P(None, None),
                   P("model", None, None, ff_axis),
                   P("model", None, None, ff_axis),
                   P("model", None, ff_axis, None)),
         out_specs=(P(None, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, params["router"], params["wi"], params["wg"], params["wo"])
 
     if cfg.n_shared_experts:

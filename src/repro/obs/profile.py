@@ -7,9 +7,8 @@ XLA. ``JitProfiler`` wraps a ``jax.jit`` callable and makes that visible:
 
 * the first call for a distinct argument-shape key AOT-compiles via
   ``fn.lower(*args).compile()`` and records a :class:`CompileEvent` —
-  wall-clock compile seconds plus, where ``Compiled.cost_analysis`` works
-  (normalized list-vs-dict by the ``repro.dist.compat`` shim), the
-  estimated FLOPs and bytes-accessed of the executable;
+  wall-clock compile seconds plus, where ``Compiled.cost_analysis`` works,
+  the estimated FLOPs and bytes-accessed of the executable;
 * subsequent calls with the same shapes dispatch the cached executable
   (donation declared on the wrapped jit is honored — AOT compiles inherit
   ``donate_argnums``).
@@ -34,8 +33,6 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import jax
 
-from repro.dist import compat as _compat  # noqa: F401  (cost_analysis shim)
-
 
 @dataclasses.dataclass(frozen=True)
 class CompileEvent:
@@ -51,14 +48,22 @@ class CompileEvent:
 
 
 def shape_key(args: Tuple[Any, ...]) -> str:
-    """Stable key for the arg shapes/dtypes that decide re-compilation."""
+    """Stable key for the arg shapes/dtypes that decide re-compilation,
+    plus the devices of the first placed array: an AOT executable is bound
+    to the devices it was lowered for, so replicas on different devices
+    each compile their own."""
     parts = []
+    devices = None
     for leaf in jax.tree_util.tree_leaves(args):
         shape = getattr(leaf, "shape", None)
         if shape is not None:
             parts.append(f"{getattr(leaf, 'dtype', '?')}{list(shape)}")
         else:
             parts.append(repr(leaf))
+        if devices is None and isinstance(leaf, jax.Array):
+            devices = sorted(d.id for d in leaf.sharding.device_set)
+    if devices is not None:
+        parts.append(f"devices{devices}")
     return ",".join(parts)
 
 

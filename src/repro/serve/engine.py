@@ -214,7 +214,10 @@ class Engine:
                   router/bench to place data-parallel replicas on distinct
                   devices of the host mesh; mutually exclusive with an
                   active sharding mesh. Default None = jax's default
-                  placement (unchanged single-engine behavior).
+                  placement (unchanged single-engine behavior). Under an
+                  active mesh the cache is sharded by ``paged_cache_spec``
+                  and the params by ``param_spec`` (deployed KAN artifacts
+                  replicate).
     recorder    : optional ``repro.obs.EngineRecorder``. Default is the
                   no-op ``NullRecorder`` — the tick path then contains no
                   timing calls and no profiled jits. With a recorder, the
@@ -273,6 +276,15 @@ class Engine:
             shardings = shlib.tree_shardings(self.mesh, self.cache,
                                              dec.paged_cache_spec(cfg))
             self.cache = jax.device_put(self.cache, shardings)
+            if self.kan_deployed:
+                # deployed KAN artifacts no longer match param_spec; KAN-FFN
+                # models are small, so they replicate
+                pshard = jax.sharding.NamedSharding(
+                    self.mesh, jax.sharding.PartitionSpec())
+            else:
+                pshard = shlib.tree_shardings(self.mesh, self.params,
+                                              tfm.param_spec(cfg))
+            self.params = jax.device_put(self.params, pshard)
         elif device is not None:
             self.params = jax.device_put(self.params, device)
             self.cache = jax.device_put(self.cache, device)
@@ -791,6 +803,30 @@ def synth_trace(vocab: int, n_requests: int, *, max_prompt: int = 12,
             priority=i % n_priorities,
             arrival=i * stagger))
     return reqs
+
+
+def make_replicas(params, cfg: ModelConfig, n_replicas: int, *,
+                  adopt_from: Optional[Engine] = None, recorder_for=None,
+                  **engine_kw) -> List[Engine]:
+    """Data-parallel replicas for ``serve.router.Router``: replica i is
+    pinned whole to ``jax.devices()[i % n_devices]``. Replica 0 deploys any
+    KAN artifacts once; the others copy its deployed params to their own
+    device and adopt its jitted callables (jit still compiles once per
+    device). ``adopt_from`` warm-starts replica 0 from an engine of the
+    same geometry; ``recorder_for(i)`` gives replica i's recorder;
+    ``engine_kw`` is the shared geometry (``n_slots``, ``max_len``, ...)."""
+    devices = jax.devices()
+    rec = recorder_for or (lambda i: None)
+    first = Engine(params, cfg, device=devices[0], recorder=rec(0),
+                   **engine_kw)
+    if adopt_from is not None:
+        first.adopt_compiled(adopt_from)
+    replicas = [first]
+    for i in range(1, n_replicas):
+        replicas.append(Engine(
+            first.params, cfg, device=devices[i % len(devices)],
+            recorder=rec(i), **engine_kw).adopt_compiled(first))
+    return replicas
 
 
 def generate_dynamic(params, cfg: ModelConfig, prompts: Sequence,

@@ -84,7 +84,6 @@ COMPRESS_SCRIPT = textwrap.dedent("""
     import os
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.dist import compress
 
@@ -99,10 +98,10 @@ COMPRESS_SCRIPT = textwrap.dedent("""
             {"w": g[0]}, {"w": e[0].reshape(-1)}, axis="pod")
         return out["w"][None], new_e["w"][None]
 
-    out, new_ef = shard_map(fn, mesh=mesh,
-                            in_specs=(P("pod"), P("pod")),
-                            out_specs=(P("pod"), P("pod")),
-                            check_rep=False)(grads, ef)
+    out, new_ef = jax.shard_map(fn, mesh=mesh,
+                                in_specs=(P("pod"), P("pod")),
+                                out_specs=(P("pod"), P("pod")),
+                                check_vma=False)(grads, ef)
     want = grads.mean(axis=0)
     got = out[0]
     rel = float(jnp.linalg.norm(got - want) / jnp.linalg.norm(want))

@@ -2,7 +2,8 @@
 
 Pins the acceptance matrix of the unified KAN API:
 * all four backends run the SAME deployed params through ONE ``kan.apply``;
-* ``lut`` vs ``fused`` bit-identical (same frozen artifact, same dataflow);
+* ``lut`` vs ``fused`` within a few ulp (same frozen artifact, same basis
+  values, different summation order);
 * ``ref`` within spline-input-quantization tolerance;
 * ``cim`` with an ideal (no IR-drop / no noise / fine DAC+ADC) crossbar
   matches ``lut``;
@@ -57,11 +58,14 @@ def test_backend_matrix_parity():
             for b in BACKENDS}
     for b in BACKENDS:
         assert outs[b].shape == (32, 8)
-    # lut vs fused: identical frozen artifact through the identical
-    # quantize->SH-LUT->expand->contract dataflow; a single-tile problem is
-    # bit-identical (multi-tile accumulation order is covered below)
-    np.testing.assert_array_equal(np.asarray(outs["lut"]),
-                                  np.asarray(outs["fused"]))
+    # lut vs fused: identical frozen artifact and identical basis values,
+    # but the two backends sum the contraction in a different order (lut:
+    # one dot over I*S; fused: one dot per basis slot, accumulated), so f32
+    # rounding may differ by a few ulp of the output scale
+    y_lut = np.asarray(outs["lut"])
+    ulp = np.spacing(np.abs(y_lut).max())
+    np.testing.assert_allclose(np.asarray(outs["fused"]), y_lut, rtol=0,
+                               atol=4 * ulp)
     # ref: float recursive basis over the dequantized codes — differs from
     # lut by input-quantization error only
     np.testing.assert_allclose(outs["ref"], outs["lut"], atol=0.1)

@@ -689,6 +689,34 @@ def test_router_real_engines_drain_keeps_tokens():
         assert len(c_tokens) > 0
 
 
+def test_make_replicas_pins_each_replica_to_its_own_device():
+    """``make_replicas`` (what ``launch.serve --replicas`` builds) puts
+    replica i's params and cache on ``jax.devices()[i]``; the pinned fleet,
+    recorded (per-device AOT executables), still matches one engine."""
+    import jax
+    from repro.configs import get_arch
+    from repro.models import transformer as tfm
+    from repro.serve.engine import Engine, make_replicas, synth_trace
+
+    devices = jax.devices()
+    assert len(devices) >= 4, "conftest forces 8 host devices"
+    m = get_arch("mamba2_1p3b", smoke=True).model
+    params = tfm.init_model(jax.random.PRNGKey(2), m)
+    kw = dict(n_slots=1, max_len=16)
+    parent = EngineRecorder()
+    fleet = make_replicas(params, m, 4, recorder_for=parent.for_replica, **kw)
+    for i, eng in enumerate(fleet):
+        placed = {d for leaf in jax.tree.leaves((eng.params, eng.cache))
+                  for d in leaf.devices()}
+        assert placed == {devices[i]}, (i, placed)
+    reqs = synth_trace(m.vocab, 4, max_prompt=8, min_prompt=8, max_new=3,
+                       min_new=3, stagger=0, seed=7)
+    ref = _completion_map(Engine(params, m, **kw).run(reqs))
+    router = Router(fleet)
+    assert _completion_map(router.run(reqs)) == ref
+    assert all(n == 1 for n in router.report()["routed"])
+
+
 # ---------------------------------------------------------------------------
 # HealthMonitor: closed-loop auto-drain
 # ---------------------------------------------------------------------------
