@@ -140,39 +140,13 @@ def probe_cell(arch_name: str, shape_name: str, multi_pod: bool = False,
     return rec
 
 
-def obs_table(path: str) -> None:
-    """Per-callable roofline terms from a serving obs snapshot
-    (``launch.serve --metrics-out`` / ``obs.EngineRecorder.snapshot()``):
-    the recorder's ``compiled_flops``/``compiled_bytes`` gauges — XLA
-    ``cost_analysis`` estimates captured at compile time — run through the
-    same ``analysis.roofline_terms`` model as the probe cells."""
-    from repro.obs.profile import roofline_rows
-    with open(path) as f:
-        snap = json.load(f)
-    rows = roofline_rows(snap)
-    if not rows:
-        raise SystemExit(f"{path}: no compiled_flops/compiled_bytes gauges "
-                         "(was the run recorded?)")
-    print(f"per-callable roofline from {path}:")
-    for r in rows:
-        print(f"  {r['fn']}: flops={r['flops']:.3e} bytes={r['bytes']:.3e} "
-              f"compute={r['t_compute_s']:.3e}s memory={r['t_memory_s']:.3e}s"
-              f" dom={r['dominant']}")
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch")
     ap.add_argument("--shape")
     ap.add_argument("--multi-pod", action="store_true")
     ap.add_argument("--all", action="store_true")
-    ap.add_argument("--from-obs", default="",
-                    help="print per-callable roofline terms from an obs "
-                         "metrics snapshot instead of probing cells")
     args = ap.parse_args()
-    if args.from_obs:
-        obs_table(args.from_obs)
-        return
     cells = ([(a, s) for a, s, ok in cfglib.lm_cells() if ok]
              if args.all else [(args.arch, args.shape)])
     for a, s in cells:

@@ -450,27 +450,33 @@ def _mlp_ffn(p, x, cfg: ModelConfig):
 def _apply_layer(p, x, spec: LayerSpec, cfg: ModelConfig, positions,
                  enc_out=None):
     aux = {}
-    if spec.mixer in ("attn", "swa", "local", "bidir"):
-        x = x + _attn_mixer(p, x, cfg, spec, positions, enc_out)
-    elif spec.mixer == "rglru":
-        xn = layers.NORM_APPLY[cfg.norm](p["mixer_norm"], x)
-        x = x + rglru_lib.apply_rglru_block(p["rglru"], xn, cfg.rglru_cfg
+    # the scopes group a trace's ops as serving's layers do (serve/decode.py)
+    with jax.named_scope("mixer"):
+        if spec.mixer in ("attn", "swa", "local", "bidir"):
+            x = x + _attn_mixer(p, x, cfg, spec, positions, enc_out)
+        elif spec.mixer == "rglru":
+            xn = layers.NORM_APPLY[cfg.norm](p["mixer_norm"], x)
+            x = x + rglru_lib.apply_rglru_block(p["rglru"], xn,
+                                                cfg.rglru_cfg
+                                                ).astype(x.dtype)
+        elif spec.mixer == "ssd":
+            xn = layers.NORM_APPLY[cfg.norm](p["mixer_norm"], x)
+            x = x + ssd_lib.apply_ssd_block(p["ssd"], xn, cfg.ssd_cfg
                                             ).astype(x.dtype)
-    elif spec.mixer == "ssd":
-        xn = layers.NORM_APPLY[cfg.norm](p["mixer_norm"], x)
-        x = x + ssd_lib.apply_ssd_block(p["ssd"], xn, cfg.ssd_cfg
-                                        ).astype(x.dtype)
-    if spec.cross_attn and enc_out is not None:
-        x = x + _cross_mixer(p, x, cfg, enc_out)
-    if spec.ffn == "mlp":
-        x = x + _mlp_ffn(p, x, cfg)
-    elif spec.ffn == "moe":
-        xn = layers.NORM_APPLY[cfg.norm](p["ffn_norm"], x)
-        y, aux = moe_lib.apply_moe(p["moe"], xn, cfg.moe_cfg)
-        x = x + y
-    elif spec.ffn == "kan":
-        xn = layers.NORM_APPLY[cfg.norm](p["ffn_norm"], x)
-        x = x + kan.apply_any(p["kan"], xn, cfg.kan_spec).astype(x.dtype)
+        if spec.cross_attn and enc_out is not None:
+            x = x + _cross_mixer(p, x, cfg, enc_out)
+    with jax.named_scope("ffn"):
+        if spec.ffn == "mlp":
+            x = x + _mlp_ffn(p, x, cfg)
+        elif spec.ffn == "moe":
+            xn = layers.NORM_APPLY[cfg.norm](p["ffn_norm"], x)
+            y, aux = moe_lib.apply_moe(p["moe"], xn, cfg.moe_cfg)
+            x = x + y
+        elif spec.ffn == "kan":
+            xn = layers.NORM_APPLY[cfg.norm](p["ffn_norm"], x)
+            with jax.named_scope("kan_ffn"):
+                x = x + kan.apply_any(p["kan"], xn,
+                                      cfg.kan_spec).astype(x.dtype)
     x = shard(x, "batch", "seq_sp" if cfg.seq_shard_activations else "seq",
               None)
     return x, aux

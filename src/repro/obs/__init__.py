@@ -1,20 +1,21 @@
 """repro.obs — observability for the serving/kernel/chip stack.
 
-Zero-dependency telemetry in three parts, tied together by a recorder:
+Zero-dependency telemetry in two parts, tied together by a recorder:
 
 * ``metrics``  — process-local Counter/Gauge/Histogram registry with
                  log-spaced latency buckets, JSON ``snapshot()`` and
                  Prometheus text ``exposition()``.
 * ``trace``    — span-based flight recorder (bounded ring buffer) that
                  exports Chrome ``trace_event`` JSON for Perfetto.
-* ``profile``  — jit wrappers that record XLA compile events (count + wall
-                 time per distinct shape key) and ``cost_analysis``
-                 FLOPs/bytes, feeding ``benchmarks/roofline.py --from-obs``.
 
 ``recorder.EngineRecorder`` is what you hand to ``serve.engine.Engine``;
-the default ``NullRecorder`` keeps the hot path untouched. ``hw.chip``
-publishes chip placement/utilization telemetry into the same registry, so
-one ``EngineRecorder.snapshot()`` describes the whole stack.
+the default ``NullRecorder`` keeps the hot path untouched. The recording
+one also marks each tick phase as a ``jax.profiler`` annotation
+(``engine.<phase>``) and counts XLA compiles per program through a
+``jax.monitoring`` listener; it imports jax when constructed, never at
+import time. ``hw.chip`` publishes chip placement/utilization telemetry
+into the same registry, so one ``EngineRecorder.snapshot()`` describes the
+whole stack.
 
 Fleet-health additions (all stdlib-only):
 
@@ -27,9 +28,7 @@ Fleet-health additions (all stdlib-only):
                  (``MetricsHTTPServer``) + periodic JSON snapshots
                  (``PeriodicSnapshotWriter``).
 
-Note: ``metrics``, ``trace``, ``sketch``, ``slo`` and ``export`` are
-stdlib-only; ``profile`` imports jax, so it is NOT re-exported here —
-import ``repro.obs.profile`` directly.
+Every module here is stdlib-only at import.
 """
 from repro.obs.export import (MetricsHTTPServer,  # noqa: F401
                               PeriodicSnapshotWriter)

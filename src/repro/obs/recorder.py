@@ -1,20 +1,30 @@
 """Engine-facing recording API: NullRecorder (default, no-op) and
-EngineRecorder (metrics + trace + compile profiling in one object).
+EngineRecorder (metrics + trace + compile events in one object).
 
 The serving engine does not talk to registries or ring buffers directly —
 it calls a small semantic vocabulary (``on_submit`` / ``on_admit`` /
-``on_first_token`` / ``on_decode_tick`` / ``on_evict`` / ``phase`` /
-``on_compile``) on whatever recorder it was built with:
+``on_first_token`` / ``on_decode_tick`` / ``on_evict`` / ``phase``) on
+whatever recorder it was built with:
 
 * :class:`NullRecorder` — the default. Every hook is a ``pass`` and
   ``phase()`` hands back a shared do-nothing context manager, so the
   disabled hot path costs an attribute lookup and nothing else (no
-  ``perf_counter`` calls, no event objects, no jaxpr change — the
-  batching-invariance and requant-free pins run against this path).
+  ``perf_counter`` calls, no event objects, no profiler annotation, no
+  jaxpr change — the batching-invariance and requant-free pins run
+  against this path).
 * :class:`EngineRecorder` — owns a :class:`~repro.obs.metrics.MetricsRegistry`
-  and a :class:`~repro.obs.trace.TraceRecorder`, translates each hook into
-  counters/histograms *and* Chrome trace events, and accumulates
-  :class:`~repro.obs.profile.CompileEvent` records from profiled jits.
+  and a :class:`~repro.obs.trace.TraceRecorder` and translates each hook
+  into counters/histograms *and* Chrome trace events. Each ``phase()`` is
+  also a ``jax.profiler.TraceAnnotation`` named ``engine.<phase>``, so a
+  ``jax.profiler`` trace holds the tick's phases in its host plane, on the
+  same clock as the device's ops.
+
+The recorder never touches the engine's jits. Compiles are observed through
+one process-wide ``jax.monitoring`` listener on XLA's backend-compile
+event, keyed by the program's name (the engine names every jit:
+``decode_tick``, ``prefill_chunk64_first``, ...). Each event goes to the
+recorder whose ``phase()`` is open at the time: the one whose engine is
+compiling.
 
 ``snapshot()`` is the one-stop description of the stack: metrics (TTFT /
 TPOT / queue-wait / tick-phase histograms, compile counters, any chip
@@ -25,10 +35,11 @@ compile event list. Schema ``obs/v1`` — validated by
 from __future__ import annotations
 
 import contextlib
+import dataclasses
 import json
 import os
 import time
-from typing import Dict, Optional, Tuple
+from typing import Dict, List, Optional, Tuple
 
 from repro.obs.metrics import MetricsRegistry
 from repro.obs.trace import TID_REQUEST, TraceRecorder
@@ -41,6 +52,47 @@ QUEUE_WAIT_BUCKETS = tuple(float(2 ** i) for i in range(11))
 
 _NULL_CTX = contextlib.nullcontext()
 
+#: XLA's compile of one program (or its load from the persistent cache)
+COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+#: recorders whose ``phase()`` is open, innermost last: a compile event
+#: goes to the last one. Process-wide, as jax's monitoring listeners are.
+_ACTIVE: List["EngineRecorder"] = []
+_listening = False
+
+
+@dataclasses.dataclass(frozen=True)
+class CompileEvent:
+    """One XLA compile of a program, named as the engine named its jit."""
+    name: str                 # "decode_tick", "prefill_len12", ...
+    wall_s: float             # compile (or persistent-cache load) seconds
+
+    def as_dict(self) -> dict:
+        """Plain-dict form for the ``obs/v1`` snapshot."""
+        return dataclasses.asdict(self)
+
+
+def program_name(fun_name: str) -> str:
+    """``jit(decode_tick)`` -> ``decode_tick``."""
+    if fun_name.startswith("jit(") and fun_name.endswith(")"):
+        return fun_name[4:-1]
+    return fun_name
+
+
+def _on_duration(event: str, duration: float, **kw) -> None:
+    if event == COMPILE_EVENT and _ACTIVE:
+        _ACTIVE[-1].on_compile(CompileEvent(
+            program_name(str(kw.get("fun_name", "?"))), duration))
+
+
+def _listen() -> None:
+    """Register the process's one compile listener (first recorder only)."""
+    global _listening
+    if not _listening:
+        from jax import monitoring
+        monitoring.register_event_duration_secs_listener(_on_duration)
+        _listening = True
+
 
 class NullRecorder:
     """Do-nothing recorder: the engine's default. Keeps the tick path free
@@ -50,7 +102,7 @@ class NullRecorder:
     metrics: Optional[MetricsRegistry] = None
     trace: Optional[TraceRecorder] = None
 
-    def phase(self, name: str):
+    def phase(self, name: str, **args):
         """Shared do-nothing context manager (no timer, no allocation)."""
         return _NULL_CTX
 
@@ -84,8 +136,8 @@ class NullRecorder:
     def on_prefix(self, matched: int, eligible: int) -> None:
         """Prefix-cache outcome of one admission (pages hit vs probed)."""
 
-    def on_compile(self, event) -> None:
-        """A profiled jit paid an XLA compile."""
+    def on_compile(self, event: CompileEvent) -> None:
+        """A program compiled while one of this recorder's phases ran."""
 
     def snapshot(self) -> dict:
         """Telemetry summary; empty for the no-op recorder."""
@@ -93,7 +145,7 @@ class NullRecorder:
 
 
 class EngineRecorder(NullRecorder):
-    """Metrics + trace + compile profiling for one engine (or several —
+    """Metrics + trace + compile events for one engine (or several —
     sharing one recorder across engines merges their telemetry).
 
     ``labels`` (optional) is merged into every metric this recorder
@@ -114,7 +166,11 @@ class EngineRecorder(NullRecorder):
         self.trace = (trace if trace is not None
                       else TraceRecorder(capacity=trace_capacity))
         self.labels = dict(labels) if labels else None
-        self.compile_events: list = []
+        self.compile_events: List[CompileEvent] = []
+        self._phase_h: Dict[str, object] = {}
+        _listen()
+        from jax.profiler import TraceAnnotation
+        self._annotation = TraceAnnotation
         # rid -> (submit wall perf_counter, submit tick)
         self._submitted: Dict[object, Tuple[float, int]] = {}
         m = self.metrics
@@ -263,38 +319,35 @@ class EngineRecorder(NullRecorder):
 
     # -- tick phases ---------------------------------------------------------
 
-    def phase(self, name: str):
-        """Time one engine tick phase into both the per-phase latency
-        histogram and a nested trace span."""
-        hist = self.metrics.histogram("serve_tick_phase_seconds",
-                                      "engine tick phase wall time",
-                                      labels=self._labels({"phase": name}))
-        return _PhaseTimer(self, name, hist)
+    def phase(self, name: str, **args):
+        """Time one engine tick phase into the per-phase latency histogram,
+        a nested ring-buffer span and a ``jax.profiler.TraceAnnotation``
+        ``engine.<name>`` (``args``, e.g. a chunk's ``rid``/``slot``, ride
+        on both spans). Phases nest."""
+        hist = self._phase_h.get(name)
+        if hist is None:
+            hist = self._phase_h[name] = self.metrics.histogram(
+                "serve_tick_phase_seconds", "engine tick phase wall time",
+                labels=self._labels({"phase": name}))
+        if args:
+            args = {k: v if isinstance(v, (int, str)) else str(v)
+                    for k, v in args.items()}
+        return _PhaseSpan(self, name, hist, args)
 
     # -- compiles ------------------------------------------------------------
 
-    def on_compile(self, event) -> None:
-        """Record a CompileEvent: counter + wall-time histogram + FLOPs /
-        bytes cost gauges + an instant trace marker."""
+    def on_compile(self, event: CompileEvent) -> None:
+        """Record a CompileEvent: counter + wall-time histogram + an
+        instant trace marker."""
         self.compile_events.append(event)
         labels = {"fn": event.name}
         self.metrics.counter("compile_total",
-                             "XLA compiles per callable", labels=labels).inc()
+                             "XLA compiles per program", labels=labels).inc()
         self.metrics.histogram("compile_seconds",
-                               "lower+compile wall time",
+                               "XLA compile wall time",
                                labels=labels).observe(event.wall_s)
-        if event.flops is not None:
-            self.metrics.gauge("compiled_flops",
-                               "cost_analysis FLOPs estimate (latest "
-                               "compile)", labels=labels).set(event.flops)
-        if event.bytes_accessed is not None:
-            self.metrics.gauge("compiled_bytes",
-                               "cost_analysis bytes-accessed estimate "
-                               "(latest compile)",
-                               labels=labels).set(event.bytes_accessed)
         self.trace.instant("compile", args={
-            "fn": event.name, "key": event.key,
-            "wall_ms": round(event.wall_s * 1e3, 1)})
+            "fn": event.name, "wall_ms": round(event.wall_s * 1e3, 1)})
 
     # -- export --------------------------------------------------------------
 
@@ -319,28 +372,37 @@ class EngineRecorder(NullRecorder):
         return self.trace.export(path)
 
 
-class _PhaseTimer:
-    """Context manager: one phase -> histogram observation + trace span.
-    ``dur_s`` holds the measured duration after exit (the engine reuses the
-    decode-phase duration as the tick's per-token TPOT)."""
+class _PhaseSpan:
+    """Context manager: one phase -> histogram observation, ring-buffer
+    span and profiler annotation. ``dur_s`` holds the measured duration
+    after exit (the engine's TPOT is the decode dispatch plus its
+    readback). While it is open, compiles go to its recorder."""
 
-    __slots__ = ("rec", "name", "hist", "dur_s", "_t0")
+    __slots__ = ("rec", "name", "hist", "args", "dur_s", "_t0", "_ann")
 
-    def __init__(self, rec: EngineRecorder, name: str, hist):
+    def __init__(self, rec: EngineRecorder, name: str, hist, args: dict):
         self.rec = rec
         self.name = name
         self.hist = hist
+        self.args = args
         self.dur_s = 0.0
         self._t0 = 0.0
+        self._ann = None
 
     def __enter__(self):
+        _ACTIVE.append(self.rec)
+        self._ann = self.rec._annotation(f"engine.{self.name}", **self.args)
+        self._ann.__enter__()
         self._t0 = time.perf_counter()
         return self
 
     def __exit__(self, *exc):
         self.dur_s = time.perf_counter() - self._t0
+        self._ann.__exit__(*exc)
+        _ACTIVE.pop()
         self.hist.observe(self.dur_s)
         self.rec.trace.complete(self.name,
                                 self.rec.trace.now_us() - self.dur_s * 1e6,
-                                self.dur_s * 1e6, cat="tick")
+                                self.dur_s * 1e6, cat="tick",
+                                args=self.args or None)
         return False

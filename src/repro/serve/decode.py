@@ -23,6 +23,14 @@ chunk at a time so prefill interleaves into decode ticks (docs/serving.md
 covers the exactness argument per layer family; ``chunk_tokens_for``
 returns the largest chunk unit that keeps the math identical to a solo
 run, or None for families that must prefill in one piece).
+
+Every op of a decode or prefill-chunk layer sits under one
+``jax.named_scope`` a device trace can group by: ``mixer`` (norm, QKV,
+rope, attention or the SSD/rgLRU block),
+``cache_update`` (K/V pool writes, recurrent-state write masks), ``ffn``
+(MLP, MoE or KAN-FFN) and, after the layers, ``unembed`` (final norm,
+logits, argmax). Finer scopes nest inside them: ``paged_attention``,
+``ssd_scan``, ``kan_ffn``. Scopes are op metadata only.
 """
 from __future__ import annotations
 
@@ -238,6 +246,37 @@ def _mask_state_writes(new, cache, pages: Optional[Array]):
             for k, v in new.items()}
 
 
+def _ffn(p, x, spec: LayerSpec, cfg: ModelConfig, **moe_kw):
+    """The layer's FFN residual update (MLP, MoE or KAN-FFN) under the
+    ``ffn`` scope. DeployedKAN subtrees (``tfm.deploy_kan``) run the frozen
+    integer artifact; raw param trees run the float training path."""
+    if spec.ffn not in ("mlp", "moe", "kan"):
+        return x
+    with jax.named_scope("ffn"):
+        if spec.ffn == "mlp":
+            return x + tfm._mlp_ffn(p, x, cfg)
+        xn = layers.NORM_APPLY[cfg.norm](p["ffn_norm"], x)
+        if spec.ffn == "moe":
+            y, _ = moe_lib.apply_moe(p["moe"], xn, cfg.moe_cfg, **moe_kw)
+            return x + y
+        with jax.named_scope("kan_ffn"):
+            return x + kan.apply_any(p["kan"], xn,
+                                     cfg.kan_spec).astype(x.dtype)
+
+
+def _unembed(params, x, cfg: ModelConfig) -> Array:
+    """Final norm and logits (soft-capped where the config says) under the
+    ``unembed`` scope."""
+    with jax.named_scope("unembed"):
+        x = layers.NORM_APPLY[cfg.norm](params["final_norm"], x)
+        table = params.get("unembed", params["embed"])
+        logits = layers.unembed(x, table.astype(cfg.dtype))
+        if cfg.logits_softcap:
+            logits = cfg.logits_softcap * jnp.tanh(
+                logits / cfg.logits_softcap)
+        return logits
+
+
 def _decode_layer(p, cache, x, spec: LayerSpec, cfg: ModelConfig,
                   index: Array, pages: Optional[Array] = None):
     """x: [B, 1, D]; index: count of tokens so far (0-based position of the
@@ -246,68 +285,71 @@ def _decode_layer(p, cache, x, spec: LayerSpec, cfg: ModelConfig,
     paged pool layout; all other layer kinds ignore it."""
     new_cache = dict(cache)
     if spec.mixer == "attn" and pages is not None:
-        xn = layers.NORM_APPLY[cfg.norm](p["mixer_norm"], x)
-        q, k, v = _qkv(p, xn, cfg)
-        if cfg.rope_theta:
-            pos = index[:, None] if index.ndim else jnp.full((1, 1), index)
-            q = layers.apply_rope(q, pos, cfg.rope_theta)
-            k = layers.apply_rope(k, pos, cfg.rope_theta)
-        bidx = jnp.broadcast_to(jnp.asarray(index), (x.shape[0],))
-        kp, vp = attn_lib.paged_cache_update(cache["k"], cache["v"], k, v,
-                                             pages, bidx)
+        with jax.named_scope("mixer"):
+            xn = layers.NORM_APPLY[cfg.norm](p["mixer_norm"], x)
+            q, k, v = _qkv(p, xn, cfg)
+            if cfg.rope_theta:
+                pos = (index[:, None] if index.ndim
+                       else jnp.full((1, 1), index))
+                q = layers.apply_rope(q, pos, cfg.rope_theta)
+                k = layers.apply_rope(k, pos, cfg.rope_theta)
+            bidx = jnp.broadcast_to(jnp.asarray(index), (x.shape[0],))
+        with jax.named_scope("cache_update"):
+            kp, vp = attn_lib.paged_cache_update(cache["k"], cache["v"], k,
+                                                 v, pages, bidx)
         new_cache["k"], new_cache["v"] = kp, vp
-        ck = attn_lib.paged_gather(kp, pages)
-        cv = attn_lib.paged_gather(vp, pages)
-        o = attn_lib.decode_attention(q, ck, cv, bidx + 1)
-        x = x + jnp.einsum("bshk,hkd->bsd", o,
-                           p["attn"]["wo"].astype(cfg.dtype))
+        with jax.named_scope("mixer"):
+            with jax.named_scope("paged_attention"):
+                ck = attn_lib.paged_gather(kp, pages)
+                cv = attn_lib.paged_gather(vp, pages)
+                o = attn_lib.decode_attention(q, ck, cv, bidx + 1)
+            x = x + jnp.einsum("bshk,hkd->bsd", o,
+                               p["attn"]["wo"].astype(cfg.dtype))
     elif spec.mixer in ("attn", "swa", "local"):
-        xn = layers.NORM_APPLY[cfg.norm](p["mixer_norm"], x)
-        q, k, v = _qkv(p, xn, cfg)
-        if cfg.rope_theta:
-            pos = index[:, None] if index.ndim else jnp.full((1,), index)
-            q = layers.apply_rope(q, pos, cfg.rope_theta)
-            k = layers.apply_rope(k, pos, cfg.rope_theta)
+        with jax.named_scope("mixer"):
+            xn = layers.NORM_APPLY[cfg.norm](p["mixer_norm"], x)
+            q, k, v = _qkv(p, xn, cfg)
+            if cfg.rope_theta:
+                pos = index[:, None] if index.ndim else jnp.full((1,), index)
+                q = layers.apply_rope(q, pos, cfg.rope_theta)
+                k = layers.apply_rope(k, pos, cfg.rope_theta)
         rolling = spec.mixer in ("swa", "local")
-        ck, cv = attn_lib.cache_update(cache["k"], cache["v"], k, v, index,
-                                       rolling=rolling)
+        with jax.named_scope("cache_update"):
+            ck, cv = attn_lib.cache_update(cache["k"], cache["v"], k, v,
+                                           index, rolling=rolling)
         new_cache["k"], new_cache["v"] = ck, cv
-        o = attn_lib.decode_attention(q, ck, cv, index + 1, rolling=rolling)
-        x = x + jnp.einsum("bshk,hkd->bsd", o,
-                           p["attn"]["wo"].astype(cfg.dtype))
-    elif spec.mixer == "ssd":
-        xn = layers.NORM_APPLY[cfg.norm](p["mixer_norm"], x)
-        y, sc = ssd_lib.apply_ssd_block_decode(
-            p["ssd"], xn, {"state": cache["state"],
-                           "conv_buf": cache["conv_buf"]}, cfg.ssd_cfg)
-        new_cache.update(_mask_state_writes(sc, cache, pages))
-        x = x + y.astype(x.dtype)
-    elif spec.mixer == "rglru":
-        xn = layers.NORM_APPLY[cfg.norm](p["mixer_norm"], x)
-        y, rc = rglru_lib.apply_rglru_block_decode(
-            p["rglru"], xn, {"h": cache["h"],
-                             "conv_buf": cache["conv_buf"]}, cfg.rglru_cfg)
-        new_cache.update(_mask_state_writes(rc, cache, pages))
-        x = x + y.astype(x.dtype)
+        with jax.named_scope("mixer"):
+            o = attn_lib.decode_attention(q, ck, cv, index + 1,
+                                          rolling=rolling)
+            x = x + jnp.einsum("bshk,hkd->bsd", o,
+                               p["attn"]["wo"].astype(cfg.dtype))
+    elif spec.mixer in ("ssd", "rglru"):
+        with jax.named_scope("mixer"):
+            xn = layers.NORM_APPLY[cfg.norm](p["mixer_norm"], x)
+            if spec.mixer == "ssd":
+                y, sc = ssd_lib.apply_ssd_block_decode(
+                    p["ssd"], xn, {"state": cache["state"],
+                                   "conv_buf": cache["conv_buf"]},
+                    cfg.ssd_cfg)
+            else:
+                y, sc = rglru_lib.apply_rglru_block_decode(
+                    p["rglru"], xn, {"h": cache["h"],
+                                     "conv_buf": cache["conv_buf"]},
+                    cfg.rglru_cfg)
+        with jax.named_scope("cache_update"):
+            new_cache.update(_mask_state_writes(sc, cache, pages))
+        with jax.named_scope("mixer"):
+            x = x + y.astype(x.dtype)
     if spec.cross_attn:
-        xn = layers.NORM_APPLY[cfg.norm](p["cross_norm"], x)
-        q = jnp.einsum("bsd,dhk->bshk", xn, p["cross"]["wq"].astype(cfg.dtype))
-        o = attn_lib.decode_attention(q, cache["ck"], cache["cv"],
-                                      cache["ck"].shape[1])
-        x = x + jnp.einsum("bshk,hkd->bsd", o,
-                           p["cross"]["wo"].astype(cfg.dtype))
-    if spec.ffn == "mlp":
-        x = x + tfm._mlp_ffn(p, x, cfg)
-    elif spec.ffn == "moe":
-        xn = layers.NORM_APPLY[cfg.norm](p["ffn_norm"], x)
-        y, _ = moe_lib.apply_moe(p["moe"], xn, cfg.moe_cfg,
-                                 weights_stationary=cfg.moe_serve_stationary)
-        x = x + y
-    elif spec.ffn == "kan":
-        xn = layers.NORM_APPLY[cfg.norm](p["ffn_norm"], x)
-        # DeployedKAN subtrees (tfm.deploy_kan) run the frozen integer
-        # artifact; raw param trees run the float training path.
-        x = x + kan.apply_any(p["kan"], xn, cfg.kan_spec).astype(x.dtype)
+        with jax.named_scope("mixer"):
+            xn = layers.NORM_APPLY[cfg.norm](p["cross_norm"], x)
+            q = jnp.einsum("bsd,dhk->bshk", xn,
+                           p["cross"]["wq"].astype(cfg.dtype))
+            o = attn_lib.decode_attention(q, cache["ck"], cache["cv"],
+                                          cache["ck"].shape[1])
+            x = x + jnp.einsum("bshk,hkd->bsd", o,
+                               p["cross"]["wo"].astype(cfg.dtype))
+    x = _ffn(p, x, spec, cfg, weights_stationary=cfg.moe_serve_stationary)
     return x, new_cache
 
 
@@ -356,12 +398,7 @@ def decode_step(params, cache, tokens: Array, index: Array,
                 return xx, nc
             x, nc = jax.lax.scan(body, x, (st_params, st_cache))
             new_caches.append(nc)
-    x = layers.NORM_APPLY[cfg.norm](params["final_norm"], x)
-    table = params.get("unembed", params["embed"])
-    logits = layers.unembed(x, table.astype(cfg.dtype))
-    if cfg.logits_softcap:
-        logits = cfg.logits_softcap * jnp.tanh(logits / cfg.logits_softcap)
-    return logits, new_caches
+    return _unembed(params, x, cfg), new_caches
 
 
 # ---------------------------------------------------------------------------
@@ -426,16 +463,7 @@ def _prefill_layer(p, cache, x, spec: LayerSpec, cfg: ModelConfig,
         new_cache["ck"], new_cache["cv"] = ck, cv
         x = x + jnp.einsum("bshk,hkd->bsd", o,
                            p["cross"]["wo"].astype(cfg.dtype))
-    if spec.ffn == "mlp":
-        x = x + tfm._mlp_ffn(p, x, cfg)
-    elif spec.ffn == "moe":
-        xn = layers.NORM_APPLY[cfg.norm](p["ffn_norm"], x)
-        y, _ = moe_lib.apply_moe(p["moe"], xn, cfg.moe_cfg)
-        x = x + y
-    elif spec.ffn == "kan":
-        xn = layers.NORM_APPLY[cfg.norm](p["ffn_norm"], x)
-        x = x + kan.apply_any(p["kan"], xn, cfg.kan_spec).astype(x.dtype)
-    return x, new_cache
+    return _ffn(p, x, spec, cfg), new_cache
 
 
 def _ssd_prefill(p, x, cfg: ModelConfig):
@@ -506,9 +534,10 @@ def _ssd_prefill_chunk(p, x, cfg: ModelConfig, row: Dict[str, Array],
     xin, bmat, cmat = jnp.split(conv_out, [di, di + n], axis=-1)
     dtp = jax.nn.softplus(dt.astype(jnp.float32) + p["dt_bias"])
     a = -jnp.exp(p["a_log"])
-    y, state = ssd_lib.ssd_chunked(
-        xin.reshape(b, t, h, scfg.head_dim), dtp, a, bmat, cmat,
-        p["d_skip"], chunk=scfg.chunk, init_state=init_state)
+    with jax.named_scope("ssd_scan"):
+        y, state = ssd_lib.ssd_chunked(
+            xin.reshape(b, t, h, scfg.head_dim), dtp, a, bmat, cmat,
+            p["d_skip"], chunk=scfg.chunk, init_state=init_state)
     y = y.reshape(b, t, di).astype(x.dtype)
     y = layers.rmsnorm(p["norm"], y * jax.nn.silu(z))
     return y @ p["out_proj"], {"state": state.astype(row["state"].dtype),
@@ -526,49 +555,53 @@ def _chunk_layer(p, cache, x, spec: LayerSpec, cfg: ModelConfig,
     ``slot`` stays a traced scalar so one compiled chunk serves all slots.
     Only families ``chunk_tokens_for`` admits ever reach here."""
     new_cache = dict(cache)
+    if spec.ffn not in ("mlp", "kan", "none"):
+        raise NotImplementedError(
+            f"chunked prefill does not support ffn={spec.ffn!r}")
     if spec.mixer == "attn":
-        xn = layers.NORM_APPLY[cfg.norm](p["mixer_norm"], x)
-        q, k, v = _qkv(p, xn, cfg)
-        if cfg.rope_theta:
-            q = layers.apply_rope(q, positions, cfg.rope_theta)
-            k = layers.apply_rope(k, positions, cfg.rope_theta)
-        kp, vp = attn_lib.paged_prefill_update(cache["k"], cache["v"], k, v,
-                                               pages_row, start)
+        with jax.named_scope("mixer"):
+            xn = layers.NORM_APPLY[cfg.norm](p["mixer_norm"], x)
+            q, k, v = _qkv(p, xn, cfg)
+            if cfg.rope_theta:
+                q = layers.apply_rope(q, positions, cfg.rope_theta)
+                k = layers.apply_rope(k, positions, cfg.rope_theta)
+        with jax.named_scope("cache_update"):
+            kp, vp = attn_lib.paged_prefill_update(cache["k"], cache["v"], k,
+                                                   v, pages_row, start)
         new_cache["k"], new_cache["v"] = kp, vp
-        if first:
-            # start == 0: the chunk is self-contained — same math as solo.
-            o = attn_lib.chunked_attention(q, k, v, causal=True,
-                                           kv_chunk=cfg.attn_kv_chunk)
-        else:
-            ck = attn_lib.paged_gather(kp, pages_row[None])
-            cv = attn_lib.paged_gather(vp, pages_row[None])
-            o = attn_lib.chunked_attention(
-                q, ck, cv, causal=True, q_offset=start,
-                kv_valid_len=start + x.shape[1], kv_chunk=cfg.attn_kv_chunk)
-        x = x + jnp.einsum("bshk,hkd->bsd", o,
-                           p["attn"]["wo"].astype(cfg.dtype))
+        with jax.named_scope("mixer"):
+            with jax.named_scope("paged_attention"):
+                if first:
+                    # start == 0: the chunk is self-contained — same math
+                    # as solo.
+                    o = attn_lib.chunked_attention(
+                        q, k, v, causal=True, kv_chunk=cfg.attn_kv_chunk)
+                else:
+                    ck = attn_lib.paged_gather(kp, pages_row[None])
+                    cv = attn_lib.paged_gather(vp, pages_row[None])
+                    o = attn_lib.chunked_attention(
+                        q, ck, cv, causal=True, q_offset=start,
+                        kv_valid_len=start + x.shape[1],
+                        kv_chunk=cfg.attn_kv_chunk)
+            x = x + jnp.einsum("bshk,hkd->bsd", o,
+                               p["attn"]["wo"].astype(cfg.dtype))
     elif spec.mixer == "ssd":
-        xn = layers.NORM_APPLY[cfg.norm](p["mixer_norm"], x)
-        row = {k: jax.lax.dynamic_slice_in_dim(cache[k], slot, 1, axis=0)
-               for k in ("state", "conv_buf")}
-        y, rc = _ssd_prefill_chunk(p["ssd"], xn, cfg, row, first)
-        for k in ("state", "conv_buf"):
-            new_cache[k] = jax.lax.dynamic_update_slice_in_dim(
-                cache[k], rc[k].astype(cache[k].dtype), slot, axis=0)
-        x = x + y.astype(x.dtype)
+        with jax.named_scope("mixer"):
+            xn = layers.NORM_APPLY[cfg.norm](p["mixer_norm"], x)
+            row = {k: jax.lax.dynamic_slice_in_dim(cache[k], slot, 1, axis=0)
+                   for k in ("state", "conv_buf")}
+            y, rc = _ssd_prefill_chunk(p["ssd"], xn, cfg, row, first)
+        with jax.named_scope("cache_update"):
+            for k in ("state", "conv_buf"):
+                new_cache[k] = jax.lax.dynamic_update_slice_in_dim(
+                    cache[k], rc[k].astype(cache[k].dtype), slot, axis=0)
+        with jax.named_scope("mixer"):
+            x = x + y.astype(x.dtype)
     else:
         raise NotImplementedError(
             f"chunked prefill does not support mixer={spec.mixer!r} "
             f"(chunk_tokens_for should have returned None)")
-    if spec.ffn == "mlp":
-        x = x + tfm._mlp_ffn(p, x, cfg)
-    elif spec.ffn == "kan":
-        xn = layers.NORM_APPLY[cfg.norm](p["ffn_norm"], x)
-        x = x + kan.apply_any(p["kan"], xn, cfg.kan_spec).astype(x.dtype)
-    elif spec.ffn != "none":
-        raise NotImplementedError(
-            f"chunked prefill does not support ffn={spec.ffn!r}")
-    return x, new_cache
+    return _ffn(p, x, spec, cfg), new_cache
 
 
 def prefill_chunk(params, cfg: ModelConfig, cache, tokens: Array,
@@ -611,13 +644,10 @@ def prefill_chunk(params, cfg: ModelConfig, cache, tokens: Array,
             new_caches.append(nc)
     if not last:
         return jnp.zeros((1,), jnp.int32), new_caches
-    x = x[:, -1:]
-    x = layers.NORM_APPLY[cfg.norm](params["final_norm"], x)
-    table = params.get("unembed", params["embed"])
-    logits = layers.unembed(x, table.astype(cfg.dtype))
-    if cfg.logits_softcap:
-        logits = cfg.logits_softcap * jnp.tanh(logits / cfg.logits_softcap)
-    return jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32), new_caches
+    logits = _unembed(params, x[:, -1:], cfg)
+    with jax.named_scope("unembed"):
+        tok = jnp.argmax(logits[:, -1], axis=-1).astype(jnp.int32)
+    return tok, new_caches
 
 
 def prefill(params, cfg: ModelConfig, batch: Dict[str, Array],
@@ -663,12 +693,7 @@ def prefill(params, cfg: ModelConfig, batch: Dict[str, Array],
             new_caches.append(nc)
     if last_only:
         x = x[:, -1:]
-    x = layers.NORM_APPLY[cfg.norm](params["final_norm"], x)
-    table = params.get("unembed", params["embed"])
-    logits = layers.unembed(x, table.astype(cfg.dtype))
-    if cfg.logits_softcap:
-        logits = cfg.logits_softcap * jnp.tanh(logits / cfg.logits_softcap)
-    return logits, new_caches
+    return _unembed(params, x, cfg), new_caches
 
 
 def generate(params, cfg: ModelConfig, prompt: Array, n_new: int,

@@ -83,19 +83,33 @@ from repro.serve.scheduler import (AdmissionQueue, Completion, EngineStats,
 # bound-method closure would keep the defining engine — and its whole slot
 # pool — alive inside any callable shared through ``adopt_compiled``.
 
+
+def _named_jit(fn: functools.partial, name: str, **jit_kw):
+    """``jax.jit`` of a partial under a stable program name: the XLA module
+    (``jit_<name>`` in a device trace), the ``fun_name`` of jax's compile
+    events and the recorder's ``compile_total{fn=...}`` all carry it. The
+    name is metadata only; the compiled program is the same."""
+    fn.__name__ = fn.__qualname__ = name
+    return jax.jit(fn, **jit_kw)
+
+
 def _decode_fn(params, cache, tokens, index, pages, *, cfg):
     """Fused tick: [N] last tokens + [N] indices + [N, P] page tables ->
     next tokens. Full-attention layers read/write through ``pages``; all
     other layer families keep their per-slot rows."""
     logits, cache = dec.decode_step(params, cache, tokens[:, None], index,
                                     cfg, pages=pages)
-    return jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32), cache
+    with jax.named_scope("unembed"):
+        tok = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
+    return tok, cache
 
 
 def _prefill_fn(params, batch, *, cfg, max_len):
     logits, cache = dec.prefill(params, cfg, batch, max_len=max_len,
                                 last_only=True)
-    return jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32), cache
+    with jax.named_scope("unembed"):
+        tok = jnp.argmax(logits[:, -1, :], axis=-1).astype(jnp.int32)
+    return tok, cache
 
 
 def _chunk_fn(params, cache, tokens, start, slot, pages_row, *, cfg,
@@ -173,7 +187,7 @@ def _copy_page_fn(cache, src, dst, *, stages):
 
 
 def _chunk_jit_name(key: Tuple[int, bool, bool]) -> str:
-    """Profiler name for a chunked-prefill jit. A first-and-last chunk IS a
+    """Program name of a chunked-prefill jit. A first-and-last chunk IS a
     whole prompt, so it keeps the historical ``prefill_len{n}`` name (one
     compile per distinct prompt length — pinned by tests/test_obs.py);
     interior/terminal chunks are named by chunk length and position."""
@@ -220,11 +234,12 @@ class Engine:
                   replicate).
     recorder    : optional ``repro.obs.EngineRecorder``. Default is the
                   no-op ``NullRecorder`` — the tick path then contains no
-                  timing calls and no profiled jits. With a recorder, the
-                  engine records per-request TTFT/TPOT + queue-wait,
-                  per-tick phase timings (admit/prefill/decode/host),
-                  page-pool occupancy, prefix-cache hit counters, compile
-                  events, and the request lifecycle as Chrome trace spans.
+                  timing calls and no profiler annotations. With a
+                  recorder, the engine records per-request TTFT/TPOT +
+                  queue-wait, per-tick phase spans (see ``step``), page-pool
+                  occupancy, prefix-cache hit counters, compile events, and
+                  the request lifecycle as Chrome trace spans. The jits are
+                  the same objects with or without one.
     """
 
     def __init__(self, params, cfg: ModelConfig, *, n_slots: int,
@@ -314,46 +329,33 @@ class Engine:
         self.obs = recorder if recorder is not None else NullRecorder()
         self._prefill_jit: Dict[Tuple[int, int], object] = {}
         self._chunk_jit: Dict[Tuple[int, bool, bool], object] = {}
-        self._decode_jit = jax.jit(
-            functools.partial(_decode_fn, cfg=cfg), donate_argnums=1)
-        self._scatter_jit = jax.jit(
+        self._decode_jit = _named_jit(
+            functools.partial(_decode_fn, cfg=cfg), "decode_tick",
+            donate_argnums=1)
+        self._scatter_jit = _named_jit(
             functools.partial(_scatter_fn, stages=tuple(self.stages),
-                              page_size=page_size), donate_argnums=0)
-        self._copy_jit = jax.jit(
-            functools.partial(_copy_page_fn, stages=tuple(self.stages)),
+                              page_size=page_size), "cache_write",
             donate_argnums=0)
-        if self.obs.enabled:
-            from repro.obs import profile as obs_profile
-            self._decode_jit = obs_profile.JitProfiler(
-                self._decode_jit, "decode_tick", self.obs)
-            self._scatter_jit = obs_profile.JitProfiler(
-                self._scatter_jit, "cache_write", self.obs)
+        self._copy_jit = _named_jit(
+            functools.partial(_copy_page_fn, stages=tuple(self.stages)),
+            "copy_page", donate_argnums=0)
 
     def _prefill_for(self, prompt_len: int, enc_len: int):
         key = (prompt_len, enc_len)
         if key not in self._prefill_jit:
-            fn = jax.jit(functools.partial(
-                _prefill_fn, cfg=self.cfg, max_len=self.max_len))
-            if self.obs.enabled:
-                from repro.obs import profile as obs_profile
-                name = f"prefill_len{prompt_len}"
-                if enc_len:
-                    name += f"_enc{enc_len}"
-                fn = obs_profile.JitProfiler(fn, name, self.obs)
-            self._prefill_jit[key] = fn
+            name = f"prefill_len{prompt_len}"
+            if enc_len:
+                name += f"_enc{enc_len}"
+            self._prefill_jit[key] = _named_jit(functools.partial(
+                _prefill_fn, cfg=self.cfg, max_len=self.max_len), name)
         return self._prefill_jit[key]
 
     def _chunk_for(self, length: int, first: bool, last: bool):
         key = (length, first, last)
         if key not in self._chunk_jit:
-            fn = jax.jit(functools.partial(
+            self._chunk_jit[key] = _named_jit(functools.partial(
                 _chunk_fn, cfg=self.cfg, first=first, last=last),
-                donate_argnums=1)
-            if self.obs.enabled:
-                from repro.obs import profile as obs_profile
-                fn = obs_profile.JitProfiler(fn, _chunk_jit_name(key),
-                                             self.obs)
-            self._chunk_jit[key] = fn
+                _chunk_jit_name(key), donate_argnums=1)
         return self._chunk_jit[key]
 
     # -- admission / eviction ----------------------------------------------
@@ -473,32 +475,42 @@ class Engine:
         req = self.slot_req[slot]
         prompt = self.slot_prompt[slot]
         s = int(prompt.shape[-1])
-        pages_row = jnp.asarray(self.slot_pages[slot])
         if self.chunk_tokens is None:
-            toks = jnp.asarray(prompt.astype(np.int32))[None, :]
-            batch = {"tokens": toks}
-            enc_len = 0
-            if req.frames is not None:
-                frames = jnp.asarray(np.asarray(req.frames))[None]
-                batch["frames"] = frames
-                enc_len = frames.shape[1]
-            tok0, solo = self._prefill_for(s, enc_len)(self.params, batch)
-            self.cache = self._scatter_jit(self.cache, solo,
-                                           jnp.asarray(slot, jnp.int32),
-                                           pages_row)
-            return self._finish_prefill(slot, int(np.asarray(tok0)[0]))
+            with self.obs.phase("prefill_chunk", rid=req.rid, slot=slot,
+                                start=0, length=s):
+                pages_row = jnp.asarray(self.slot_pages[slot])
+                toks = jnp.asarray(prompt.astype(np.int32))[None, :]
+                batch = {"tokens": toks}
+                enc_len = 0
+                if req.frames is not None:
+                    frames = jnp.asarray(np.asarray(req.frames))[None]
+                    batch["frames"] = frames
+                    enc_len = frames.shape[1]
+                tok, solo = self._prefill_for(s, enc_len)(self.params, batch)
+                self.cache = self._scatter_jit(self.cache, solo,
+                                               jnp.asarray(slot, jnp.int32),
+                                               pages_row)
+            with self.obs.phase("readback"):
+                tok0 = int(np.asarray(tok)[0])
+            return self._finish_prefill(slot, tok0)
         pos = int(self.slot_pos[slot])
         length = min(self.chunk_tokens, s - pos)
         first = pos == 0
         last = pos + length == s
-        chunk = jnp.asarray(prompt[pos:pos + length].astype(np.int32))[None]
-        tok, self.cache = self._chunk_for(length, first, last)(
-            self.params, self.cache, chunk, jnp.asarray(pos, jnp.int32),
-            jnp.asarray(slot, jnp.int32), pages_row)
+        with self.obs.phase("prefill_chunk", rid=req.rid, slot=slot,
+                            start=pos, length=length):
+            pages_row = jnp.asarray(self.slot_pages[slot])
+            chunk = jnp.asarray(prompt[pos:pos + length]
+                                .astype(np.int32))[None]
+            tok, self.cache = self._chunk_for(length, first, last)(
+                self.params, self.cache, chunk, jnp.asarray(pos, jnp.int32),
+                jnp.asarray(slot, jnp.int32), pages_row)
         self.slot_pos[slot] = pos + length
         self.stats.prefill_chunks += 1
         if last:
-            return self._finish_prefill(slot, int(np.asarray(tok)[0]))
+            with self.obs.phase("readback"):
+                tok0 = int(np.asarray(tok)[0])
+            return self._finish_prefill(slot, tok0)
         return []
 
     def _finish_prefill(self, slot: int, tok0: int) -> List[Completion]:
@@ -631,7 +643,16 @@ class Engine:
     def step(self) -> List[Completion]:
         """One engine tick: admit whatever fits (slots AND pages), advance
         every prefilling slot by one chunk, then one fused decode over all
-        slots. Returns the requests completed during this tick."""
+        slots. Returns the requests completed during this tick.
+
+        Under a recorder the tick is covered by nested phase spans:
+        ``admit`` (queue peek, prefix match and page hashing, reservation),
+        ``prefill`` holding one ``prefill_chunk`` per chunk (args ``rid``,
+        ``slot``, ``start``, ``length``), ``inputs`` (decode pages, the
+        token / index / page-table arrays built and uploaded), ``decode``
+        (the dispatch), ``readback`` (each blocking device-to-host read:
+        the decode's tokens, a last chunk's first token) and ``host``
+        (token bookkeeping and evictions)."""
         done: List[Completion] = []
         obs = self.obs
         with obs.phase("admit"):
@@ -654,28 +675,31 @@ class Engine:
                     done += self._prefill_tick(int(slot))
 
         if self.active.any():
-            self._ensure_decode_pages()
-            # inactive/prefilling slots still flow through the fused step
-            # (static batch shape): index 0 keeps their garbage writes
-            # in-bounds and an all-garbage page table keeps them off every
-            # live page.
-            tokens = jnp.asarray(np.where(self.active, self.last_tok, 0)
-                                 .astype(np.int32))
-            index = jnp.asarray(np.where(self.active, self.index, 0)
-                                .astype(np.int32))
-            pages = jnp.asarray(np.where(self.active[:, None],
-                                         self.slot_pages, GARBAGE_PAGE)
-                                .astype(np.int32))
+            with obs.phase("inputs"):
+                self._ensure_decode_pages()
+                # inactive/prefilling slots still flow through the fused
+                # step (static batch shape): index 0 keeps their garbage
+                # writes in-bounds and an all-garbage page table keeps them
+                # off every live page.
+                tokens = jnp.asarray(np.where(self.active, self.last_tok, 0)
+                                     .astype(np.int32))
+                index = jnp.asarray(np.where(self.active, self.index, 0)
+                                    .astype(np.int32))
+                pages = jnp.asarray(np.where(self.active[:, None],
+                                             self.slot_pages, GARBAGE_PAGE)
+                                    .astype(np.int32))
             with obs.phase("decode") as ph:
                 nxt, self.cache = self._decode_jit(self.params, self.cache,
                                                    tokens, index, pages)
+            with obs.phase("readback") as rb:
                 nxt = np.asarray(nxt)       # blocks: real decode latency
             n_active = int(self.active.sum())
             if obs.enabled:
                 # the fused tick produced one token per active slot: each of
-                # those tokens experienced the tick's wall time as its TPOT
-                obs.on_decode_tick(n_active, ph.dur_s)
-                self.stats.tpot_s.extend([ph.dur_s] * n_active)
+                # those tokens experienced its dispatch + readback as TPOT
+                tpot = ph.dur_s + rb.dur_s
+                obs.on_decode_tick(n_active, tpot)
+                self.stats.tpot_s.extend([tpot] * n_active)
             self.stats.occupancy_ticks += n_active
             self.stats.decode_tokens += n_active
             with obs.phase("host"):
@@ -716,26 +740,6 @@ class Engine:
         self._decode_jit = other._decode_jit
         self._scatter_jit = other._scatter_jit
         self._copy_jit = other._copy_jit
-        if self.obs.enabled:
-            # re-bind adopted profilers to THIS engine's recorder (sharing
-            # their warm compiled caches); raw unprofiled jits are left
-            # untouched — re-wrapping them would force an AOT recompile
-            from repro.obs import profile as obs_profile
-
-            def rebind(fn, name):
-                if isinstance(fn, obs_profile.JitProfiler):
-                    return obs_profile.JitProfiler(fn, name, self.obs)
-                return fn
-
-            self._decode_jit = rebind(self._decode_jit, "decode_tick")
-            self._scatter_jit = rebind(self._scatter_jit, "cache_write")
-            self._prefill_jit = {
-                k: rebind(fn, f"prefill_len{k[0]}"
-                          + (f"_enc{k[1]}" if k[1] else ""))
-                for k, fn in other._prefill_jit.items()}
-            self._chunk_jit = {
-                k: rebind(fn, _chunk_jit_name(k))
-                for k, fn in other._chunk_jit.items()}
         return self
 
     def run(self, requests: Sequence[Request] = (),

@@ -1,5 +1,5 @@
-"""repro.obs: metrics registry, trace flight recorder, profiling hooks, and
-their integration with the serving engine + scheduler.
+"""repro.obs: metrics registry, trace flight recorder, compile events,
+profiler spans, and their integration with the serving engine + scheduler.
 
 Also pins the two scheduler changes that rode in with the obs layer:
 * heap-backed AdmissionQueue == the old O(n) list implementation on random
@@ -7,10 +7,15 @@ Also pins the two scheduler changes that rode in with the obs layer:
 * Engine.run() fast-forwards idle stretches to the next arrival without
   changing tokens or occupancy math (sparse-trace test).
 """
+import contextlib
+import functools
+import glob
 import json
 import math
+import re
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -19,6 +24,8 @@ from repro.models import transformer as tfm
 from repro.obs import (DEFAULT_LATENCY_BUCKETS, EngineRecorder, Histogram,
                        MetricsRegistry, NullRecorder, TraceRecorder,
                        log_buckets)
+from repro.obs import recorder as recorder_lib
+from repro.serve import engine as engine_lib
 from repro.serve.engine import Engine, synth_trace
 from repro.serve.scheduler import AdmissionQueue, Request
 
@@ -280,8 +287,13 @@ def test_recorded_engine_run_full_stack():
     assert {e["name"] for e in evs if e.get("ph") == "X"} >= {
         "admit", "prefill", "decode", "host"}
 
-    # --- recording must not change the tokens -----------------------------
+    # --- recording must not change the jits or the tokens -----------------
     plain = Engine(params, m, n_slots=2, max_len=16)
+    for attr in ("_decode_jit", "_scatter_jit", "_copy_jit"):
+        assert type(getattr(eng, attr)) is type(getattr(plain, attr))
+        assert getattr(eng, attr).__name__ == getattr(plain, attr).__name__
+    assert all(type(fn) is type(plain._decode_jit)
+               for fn in eng._chunk_jit.values())
     comps2 = plain.run(synth_trace(m.vocab, 5, max_prompt=9, min_prompt=4,
                                    max_new=6, min_new=3, stagger=2, seed=3))
     ref = {c.rid: list(c.tokens) for c in comps2}
@@ -289,10 +301,13 @@ def test_recorded_engine_run_full_stack():
 
 
 def test_compile_event_on_second_prompt_length():
-    """A new prompt length is a new silent XLA compile — the recorder must
-    surface exactly one new prefill event for it and none for a repeat."""
+    """A new prompt length is a new silent XLA compile — the recorder's
+    ``jax.monitoring`` listener must surface exactly one new prefill event
+    for it, under the jit's own name, and none for a repeat. Several
+    recorders share one process-wide listener."""
     m, params = _model()
     rec = EngineRecorder()
+    EngineRecorder()        # a second listener would count every compile twice
     eng = Engine(params, m, n_slots=1, max_len=16, recorder=rec)
     eng.run([Request(rid=0, tokens=np.arange(4) % m.vocab, max_new=2)])
     n0 = len([e for e in rec.compile_events if e.name.startswith("prefill")])
@@ -306,6 +321,10 @@ def test_compile_event_on_second_prompt_length():
     assert len([e for e in rec.compile_events
                 if e.name.startswith("prefill")]) == 2
     assert rec.metrics.get("compile_total", {"fn": "prefill_len6"}).value == 1
+    # a compile outside the engine's phases belongs to no recorder
+    n = len(rec.compile_events)
+    jax.jit(lambda x: x * 3 + 1)(jnp.arange(5.0))
+    assert len(rec.compile_events) == n
 
 
 def test_adopt_compiled_keeps_warm_caches_and_rebinds_recorder():
@@ -317,14 +336,156 @@ def test_adopt_compiled_keeps_warm_caches_and_rebinds_recorder():
     rec2 = EngineRecorder()
     eng2 = Engine(params, m, n_slots=1, max_len=12,
                   recorder=rec2).adopt_compiled(eng)
+    # the very jit objects: nothing rebinds them to the new recorder
+    assert eng2._decode_jit is eng._decode_jit
+    assert eng2._chunk_jit is eng._chunk_jit
     comps = eng2.run([Request(rid=1, tokens=np.arange(4) % m.vocab,
                               max_new=3)])
     assert len(comps) == 1
-    # warm caches: the adopting engine recompiled nothing...
+    # warm caches: the adopting engine recompiled nothing, which the
+    # listener would have handed to rec2 (its phases were the open ones)...
     assert len(rec.compile_events) == n_compiles
     assert rec2.compile_events == []
     # ...but its own recorder captured the run's latencies
     assert rec2.metrics.get("serve_ttft_seconds").count == 1
+
+
+# ---------------------------------------------------------------------------
+# program names, profiler spans, named scopes
+# ---------------------------------------------------------------------------
+
+
+def _decode_args(eng):
+    n = eng.n_slots
+    return (eng.params, eng.cache, jnp.zeros(n, jnp.int32),
+            jnp.zeros(n, jnp.int32),
+            jnp.zeros((n, eng.n_slot_pages), jnp.int32))
+
+
+def test_engine_programs_carry_stable_names():
+    """Every engine jit has a real name: the XLA module is ``jit_<name>``
+    and jax's compile events carry it as ``fun_name``."""
+    m, params = _model("mistral_nemo_12b")
+    names = []
+
+    def on(event, duration, **kw):
+        if event == recorder_lib.COMPILE_EVENT:
+            names.append(kw.get("fun_name"))
+
+    jax.monitoring.register_event_duration_secs_listener(on)
+    try:
+        eng = Engine(params, m, n_slots=2, max_len=24, page_size=4)
+        assert eng.chunk_tokens == 4
+        # 10 prompt tokens: a first chunk, an interior one, a last of 2
+        eng.run([Request(rid=0, tokens=np.arange(10) % m.vocab, max_new=3)])
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on)
+    assert {"jit(decode_tick)", "jit(prefill_chunk4_first)",
+            "jit(prefill_chunk4)", "jit(prefill_chunk2_last)"} <= set(names)
+    assert "module @jit_decode_tick" in eng._decode_jit.lower(
+        *_decode_args(eng)).as_text()
+    assert eng._scatter_jit.__name__ == "cache_write"
+    assert eng._copy_jit.__name__ == "copy_page"
+    assert recorder_lib.program_name("jit(prefill_len12)") == "prefill_len12"
+
+
+def test_profiler_trace_holds_nested_engine_spans(tmp_path):
+    """A ``jax.profiler`` session over a recorded run holds every phase as
+    an ``engine.<phase>`` host span, nested inside the caller's span; the
+    chunks nest in ``prefill`` and carry rid/slot/start/length."""
+    from jax.profiler import ProfileData
+    m, params = _model("mistral_nemo_12b")
+    eng = Engine(params, m, n_slots=2, max_len=24, page_size=4,
+                 recorder=EngineRecorder())
+    eng.run([Request(rid="warm", tokens=np.arange(10) % m.vocab,
+                     max_new=3)])           # compiles outside the trace
+    eng.submit(Request(rid="r1", tokens=(np.arange(10) + 1) % m.vocab,
+                       max_new=3))
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        while eng.active.any() or eng.prefilling.any() or len(eng.queue):
+            with jax.profiler.TraceAnnotation("engine.step"):
+                eng.step()
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    spans = [(e.name, e.start_ns, e.start_ns + e.duration_ns, dict(e.stats))
+             for plane in ProfileData.from_file(path[0]).planes
+             for line in plane.lines for e in line.events
+             if e.name.startswith("engine.")]
+    steps = [s for s in spans if s[0] == "engine.step"]
+    phases = [s for s in spans if s[0] != "engine.step"]
+    assert len(steps) == 4          # 3 chunks, then 2 decode-only ticks
+    assert {s[0] for s in phases} == {
+        "engine.admit", "engine.prefill", "engine.prefill_chunk",
+        "engine.inputs", "engine.decode", "engine.readback", "engine.host"}
+
+    def inside(inner, outer):
+        return outer[1] <= inner[1] and inner[2] <= outer[2]
+
+    assert all(any(inside(p, st) for st in steps) for p in phases)
+    prefill = [s for s in phases if s[0] == "engine.prefill"]
+    chunks = [s for s in phases if s[0] == "engine.prefill_chunk"]
+    assert all(any(inside(c, p) for p in prefill) for c in chunks)
+    assert [(c[3]["rid"], c[3]["start"], c[3]["length"]) for c in
+            sorted(chunks, key=lambda c: c[1])] == [
+        ("r1", 0, 4), ("r1", 4, 4), ("r1", 8, 2)]
+    # two readbacks of tokens: the last chunk's (in prefill), each decode's
+    reads = [s for s in phases if s[0] == "engine.readback"]
+    assert sum(any(inside(r, p) for p in prefill) for r in reads) == 1
+    assert len(reads) == 1 + sum(s[0] == "engine.decode" for s in phases)
+
+
+def test_null_recorder_tick_makes_no_timing_call_or_annotation(
+        monkeypatch):
+    """With the default recorder a tick neither reads a clock nor opens a
+    profiler annotation: the untraced program is the one measured."""
+    m, params = _model()
+    eng = Engine(params, m, n_slots=1, max_len=12)
+    eng.run([Request(rid=0, tokens=np.arange(4) % m.vocab, max_new=3)])
+
+    def forbidden(*a, **k):
+        raise AssertionError("the NullRecorder tick timed or annotated")
+
+    eng.submit(Request(rid=1, tokens=np.arange(1, 5) % m.vocab, max_new=3))
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", forbidden)
+    monkeypatch.setattr(recorder_lib.time, "perf_counter", forbidden)
+    while eng.active.any() or eng.prefilling.any() or len(eng.queue):
+        eng.step()
+    assert eng.stats.completed == 2
+
+
+def _canonical_hlo(text: str) -> str:
+    """Optimized HLO text without metadata, module name or instruction
+    numbering (a scope may change the order in which jax names ops)."""
+    text = re.sub(r"(?s)\nFileNames\n.*?\n\n(?=%|ENTRY)", "\n", text)
+    text = re.sub(r", metadata=\{[^}]*\}", "", text)
+    text = re.sub(r"^HloModule \S+", "HloModule m", text)
+    names = {}
+    return re.sub(r"%[\w\-.]+", lambda mt: names.setdefault(
+        mt.group(0), f"%v{len(names)}"), text)
+
+
+@pytest.mark.parametrize("arch,scopes", [
+    ("mamba2_1p3b", ("mixer", "cache_update", "unembed")),      # no FFN
+    ("mistral_nemo_12b", ("mixer", "cache_update", "ffn", "unembed"))])
+def test_named_scopes_change_only_op_metadata(arch, scopes, monkeypatch):
+    """The decode tick's ops carry the layer scopes in their metadata, and
+    its compiled program is the same without them."""
+    m, params = _model(arch)
+    eng = Engine(params, m, n_slots=2, max_len=16, page_size=4)
+    args = _decode_args(eng)
+    hlo = eng._decode_jit.lower(*args).compile().as_text()
+    for scope in scopes:
+        assert re.search(rf'op_name="jit\(decode_tick\)/[^"]*\b{scope}/',
+                         hlo), scope
+    scoped = _canonical_hlo(hlo)
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = engine_lib._named_jit(
+        functools.partial(engine_lib._decode_fn, cfg=m), "decode_tick",
+        donate_argnums=1)
+    assert _canonical_hlo(bare.lower(*args).compile().as_text()) == scoped
 
 
 # ---------------------------------------------------------------------------
