@@ -22,6 +22,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.layout import Layout, with_layout_constraint
 
 from repro.dist.sharding import shard
 
@@ -160,7 +161,13 @@ def decode_attention(q: Array, k_cache: Array, v_cache: Array,
     return out.reshape(b, 1, hq, hd).astype(q.dtype)
 
 
-def paged_gather(pool: Array, pages: Array) -> Array:
+def _row_major(x: Array) -> Array:
+    return with_layout_constraint(
+        x, Layout(major_to_minor=tuple(range(x.ndim))))
+
+
+def paged_gather(pool: Array, pages: Array,
+                 layer: Optional[Array] = None) -> Array:
     """Materialize per-slot K or V views from a page pool.
 
     pool: [n_pages, ps, Kv, hd] (one layer's pages, shared by all slots);
@@ -169,14 +176,20 @@ def paged_gather(pool: Array, pages: Array) -> Array:
     gathered token axis IS logical position order, so the result drops into
     ``decode_attention``/``chunked_attention`` exactly like a monolithic
     cache row (garbage-page entries land past the valid length and are
-    masked by ``cache_index``/``kv_valid_len``)."""
+    masked by ``cache_index``/``kv_valid_len``).
+
+    With ``layer`` (a traced scalar) ``pool`` is a scanned stage's stacked
+    pools [repeats, n_pages, ps, Kv, hd] and the pages are gathered at
+    ``[layer, page]`` directly: no layer's whole pool is sliced out."""
     b, p = pages.shape
-    _, ps, n_kv, hd = pool.shape
-    return pool[pages].reshape(b, p * ps, n_kv, hd)
+    ps, n_kv, hd = pool.shape[-3:]
+    got = pool[pages] if layer is None else pool[layer, pages]
+    return got.reshape(b, p * ps, n_kv, hd)
 
 
 def paged_cache_update(k_pool: Array, v_pool: Array, k_new: Array,
-                       v_new: Array, pages: Array, index: Array
+                       v_new: Array, pages: Array, index: Array,
+                       layer: Optional[Array] = None
                        ) -> Tuple[Array, Array]:
     """Scatter one decode token's K/V through the page tables.
 
@@ -185,18 +198,23 @@ def paged_cache_update(k_pool: Array, v_pool: Array, k_new: Array,
     ``pages[b, index[b] // ps]`` at offset ``index[b] % ps``. Live slots
     always target distinct pages (the engine gives every slot private write
     pages — copy-on-write forks any shared page first); inactive slots all
-    target the garbage page, where colliding writes are never read."""
-    ps = k_pool.shape[1]
+    target the garbage page, where colliding writes are never read.
+
+    With ``layer`` the pools are a scanned stage's stacked pools [repeats,
+    n_pages, ps, Kv, hd] and slot b writes ``[layer, page, offset]``: only
+    the new tokens are written, so a carried pool updates in place."""
+    ps = k_pool.shape[-3]
     index = jnp.asarray(index)
     phys = jnp.take_along_axis(pages, (index // ps)[:, None], axis=1)[:, 0]
-    within = index % ps
-    k_pool = k_pool.at[phys, within].set(k_new[:, 0].astype(k_pool.dtype))
-    v_pool = v_pool.at[phys, within].set(v_new[:, 0].astype(v_pool.dtype))
+    at = (phys, index % ps) if layer is None else (layer, phys, index % ps)
+    k_pool = k_pool.at[at].set(k_new[:, 0].astype(k_pool.dtype))
+    v_pool = v_pool.at[at].set(v_new[:, 0].astype(v_pool.dtype))
     return k_pool, v_pool
 
 
 def paged_prefill_update(k_pool: Array, v_pool: Array, k_new: Array,
-                         v_new: Array, pages_row: Array, start: Array
+                         v_new: Array, pages_row: Array, start: Array,
+                         layer: Optional[Array] = None
                          ) -> Tuple[Array, Array]:
     """Scatter one prefill chunk's K/V into a single slot's pages.
 
@@ -205,8 +223,11 @@ def paged_prefill_update(k_pool: Array, v_pool: Array, k_new: Array,
     page table. The chunk is zero-padded up to whole pages (the tail of a
     partial final page is masked garbage) and written page-at-a-time into
     ``pages_row[start//ps : start//ps + ceil(L/ps)]`` — all pages the slot
-    itself allocated, never a shared prefix page."""
-    ps = k_pool.shape[1]
+    itself allocated, never a shared prefix page.
+
+    With ``layer`` the pools are a scanned stage's stacked pools [repeats,
+    n_pages, ps, Kv, hd] and the pages are written at ``[layer, page]``."""
+    ps = k_pool.shape[-3]
     _, l, n_kv, hd = k_new.shape
     n_cp = -(-l // ps)
     pad = n_cp * ps - l
@@ -216,7 +237,14 @@ def paged_prefill_update(k_pool: Array, v_pool: Array, k_new: Array,
     kw = k_new[0].reshape(n_cp, ps, n_kv, hd).astype(k_pool.dtype)
     vw = v_new[0].reshape(n_cp, ps, n_kv, hd).astype(v_pool.dtype)
     pslice = jax.lax.dynamic_slice(pages_row, (start // ps,), (n_cp,))
-    return k_pool.at[pslice].set(kw), v_pool.at[pslice].set(vw)
+    if layer is None:
+        return k_pool.at[pslice].set(kw), v_pool.at[pslice].set(vw)
+    # The chunk's K/V leaves its projection in a layout of its own; pinned
+    # to the pools' layout as program arguments, the chunk is transposed,
+    # not the whole stacked pool on every layer (as XLA's TPU layout
+    # assignment otherwise does where a later chunk gathers from it).
+    return (_row_major(k_pool.at[layer, pslice].set(kw)),
+            _row_major(v_pool.at[layer, pslice].set(vw)))
 
 
 def cache_update(k_cache: Array, v_cache: Array, k_new: Array, v_new: Array,

@@ -8,6 +8,11 @@ Cache layouts per layer type (stacked [repeats, ...] inside scanned stages):
   rglru — hidden state [B, dr] + conv buffer.
   cross — encoder K/V computed once at prefill, read-only at decode.
 
+``decode_step`` and ``prefill_chunk`` run a scanned stage as one layer scan
+that carries the stacked cache (``_run_layers``): each layer reads its own
+rows at the layer index and writes back only the rows it changes, so a
+donated cache is updated in place.
+
 ``decode_step`` is the artifact lowered for the ``decode_32k``/``long_500k``
 dry-run cells: one new token against a cache of the given sequence length.
 SSM/hybrid archs carry O(1) state — that is their long_500k story.
@@ -246,6 +251,68 @@ def _mask_state_writes(new, cache, pages: Optional[Array]):
             for k, v in new.items()}
 
 
+def _rows(leaf: Array, layer: Optional[Array], slot: Optional[Array] = None
+          ) -> Array:
+    """One layer's rows of a cache leaf, or with ``slot`` that slot's row
+    alone (kept as a batch of one). A scanned stage's leaves are stacked
+    [repeats, B, ...] and read at ``layer``; an unstacked layer's own leaf
+    (``layer`` None) is already its rows."""
+    if layer is not None:
+        leaf = jax.lax.dynamic_index_in_dim(leaf, layer, keepdims=False)
+    if slot is not None:
+        leaf = jax.lax.dynamic_slice_in_dim(leaf, slot, 1, axis=0)
+    return leaf
+
+
+def _set_rows(leaf: Array, rows: Array, layer: Optional[Array],
+              slot: Optional[Array] = None) -> Array:
+    """Write ``rows`` back where ``_rows(leaf, layer, slot)`` read them and
+    leave the rest of the leaf as it is, so a stacked leaf carried through
+    the layer scan is updated in place."""
+    rows = rows.astype(leaf.dtype)
+    if layer is None and slot is None:
+        return rows
+    if layer is not None:
+        rows = rows[None]
+    starts = [i for i in (layer, slot) if i is not None]
+    return jax.lax.dynamic_update_slice(
+        leaf, rows, starts + [0] * (leaf.ndim - len(starts)))
+
+
+def _run_layers(params, cache, x, cfg: ModelConfig, layer_fn):
+    """Run every stage's layers over ``x``, threading the cache through.
+
+    ``layer_fn(p, c, x, spec, layer) -> (x, c)`` runs one layer on its
+    cache dict ``c``. An unstacked stage runs its layers in turn on their
+    own leaves (``layer`` None). A stacked stage (``repeats > 1``) is one
+    ``lax.scan`` over its params and the layer index that carries ``x`` and
+    the stacked cache: each layer reads its rows at the index and writes
+    back only the leaves and rows it changes, so a donated cache is updated
+    in place — no whole-cache copy, slice or write-back per call."""
+    new_caches = []
+    for st_params, st_cache, stage in zip(params["stages"], cache,
+                                          tfm.stages_for(cfg)):
+        if stage.repeats == 1:
+            nc = {}
+            for i, sp in enumerate(stage.block):
+                x, nc[f"l{i}"] = layer_fn(st_params[f"l{i}"],
+                                          st_cache[f"l{i}"], x, sp, None)
+        else:
+            def body(carry, inp, stage=stage):
+                xx, c = carry
+                lp, layer = inp
+                c = dict(c)
+                for i, sp in enumerate(stage.block):
+                    xx, c[f"l{i}"] = layer_fn(lp[f"l{i}"], c[f"l{i}"], xx,
+                                              sp, layer)
+                return (xx, c), None
+            (x, nc), _ = jax.lax.scan(
+                body, (x, st_cache),
+                (st_params, jnp.arange(stage.repeats, dtype=jnp.int32)))
+        new_caches.append(nc)
+    return x, new_caches
+
+
 def _ffn(p, x, spec: LayerSpec, cfg: ModelConfig, **moe_kw):
     """The layer's FFN residual update (MLP, MoE or KAN-FFN) under the
     ``ffn`` scope. DeployedKAN subtrees (``tfm.deploy_kan``) run the frozen
@@ -278,11 +345,13 @@ def _unembed(params, x, cfg: ModelConfig) -> Array:
 
 
 def _decode_layer(p, cache, x, spec: LayerSpec, cfg: ModelConfig,
-                  index: Array, pages: Optional[Array] = None):
+                  index: Array, pages: Optional[Array] = None,
+                  layer: Optional[Array] = None):
     """x: [B, 1, D]; index: count of tokens so far (0-based position of the
     token being decoded) — scalar, or [B] for per-slot continuous batching.
     ``pages`` ([B, P] page tables) switches full-attention layers onto the
-    paged pool layout; all other layer kinds ignore it."""
+    paged pool layout; all other layer kinds ignore it. ``layer`` indexes
+    stacked cache leaves inside the layer scan (``_run_layers``)."""
     new_cache = dict(cache)
     if spec.mixer == "attn" and pages is not None:
         with jax.named_scope("mixer"):
@@ -296,12 +365,12 @@ def _decode_layer(p, cache, x, spec: LayerSpec, cfg: ModelConfig,
             bidx = jnp.broadcast_to(jnp.asarray(index), (x.shape[0],))
         with jax.named_scope("cache_update"):
             kp, vp = attn_lib.paged_cache_update(cache["k"], cache["v"], k,
-                                                 v, pages, bidx)
+                                                 v, pages, bidx, layer)
         new_cache["k"], new_cache["v"] = kp, vp
         with jax.named_scope("mixer"):
             with jax.named_scope("paged_attention"):
-                ck = attn_lib.paged_gather(kp, pages)
-                cv = attn_lib.paged_gather(vp, pages)
+                ck = attn_lib.paged_gather(kp, pages, layer)
+                cv = attn_lib.paged_gather(vp, pages, layer)
                 o = attn_lib.decode_attention(q, ck, cv, bidx + 1)
             x = x + jnp.einsum("bshk,hkd->bsd", o,
                                p["attn"]["wo"].astype(cfg.dtype))
@@ -315,38 +384,40 @@ def _decode_layer(p, cache, x, spec: LayerSpec, cfg: ModelConfig,
                 k = layers.apply_rope(k, pos, cfg.rope_theta)
         rolling = spec.mixer in ("swa", "local")
         with jax.named_scope("cache_update"):
-            ck, cv = attn_lib.cache_update(cache["k"], cache["v"], k, v,
+            ck, cv = attn_lib.cache_update(_rows(cache["k"], layer),
+                                           _rows(cache["v"], layer), k, v,
                                            index, rolling=rolling)
-        new_cache["k"], new_cache["v"] = ck, cv
+            new_cache["k"] = _set_rows(cache["k"], ck, layer)
+            new_cache["v"] = _set_rows(cache["v"], cv, layer)
         with jax.named_scope("mixer"):
             o = attn_lib.decode_attention(q, ck, cv, index + 1,
                                           rolling=rolling)
             x = x + jnp.einsum("bshk,hkd->bsd", o,
                                p["attn"]["wo"].astype(cfg.dtype))
     elif spec.mixer in ("ssd", "rglru"):
+        keys = ("state", "conv_buf") if spec.mixer == "ssd" else (
+            "h", "conv_buf")
         with jax.named_scope("mixer"):
+            old = {k: _rows(cache[k], layer) for k in keys}
             xn = layers.NORM_APPLY[cfg.norm](p["mixer_norm"], x)
             if spec.mixer == "ssd":
-                y, sc = ssd_lib.apply_ssd_block_decode(
-                    p["ssd"], xn, {"state": cache["state"],
-                                   "conv_buf": cache["conv_buf"]},
-                    cfg.ssd_cfg)
+                y, sc = ssd_lib.apply_ssd_block_decode(p["ssd"], xn, old,
+                                                       cfg.ssd_cfg)
             else:
                 y, sc = rglru_lib.apply_rglru_block_decode(
-                    p["rglru"], xn, {"h": cache["h"],
-                                     "conv_buf": cache["conv_buf"]},
-                    cfg.rglru_cfg)
+                    p["rglru"], xn, old, cfg.rglru_cfg)
         with jax.named_scope("cache_update"):
-            new_cache.update(_mask_state_writes(sc, cache, pages))
+            for k, rows in _mask_state_writes(sc, old, pages).items():
+                new_cache[k] = _set_rows(cache[k], rows, layer)
         with jax.named_scope("mixer"):
             x = x + y.astype(x.dtype)
-    if spec.cross_attn:
+    if spec.cross_attn:                    # read-only: never written back
         with jax.named_scope("mixer"):
             xn = layers.NORM_APPLY[cfg.norm](p["cross_norm"], x)
             q = jnp.einsum("bsd,dhk->bshk", xn,
                            p["cross"]["wq"].astype(cfg.dtype))
-            o = attn_lib.decode_attention(q, cache["ck"], cache["cv"],
-                                          cache["ck"].shape[1])
+            ck, cv = _rows(cache["ck"], layer), _rows(cache["cv"], layer)
+            o = attn_lib.decode_attention(q, ck, cv, ck.shape[1])
             x = x + jnp.einsum("bshk,hkd->bsd", o,
                                p["cross"]["wo"].astype(cfg.dtype))
     x = _ffn(p, x, spec, cfg, weights_stationary=cfg.moe_serve_stationary)
@@ -377,27 +448,10 @@ def decode_step(params, cache, tokens: Array, index: Array,
             pe = jax.lax.dynamic_slice_in_dim(
                 params["dec_pos"], index, 1, axis=0)[None]
         x = x + pe.astype(cfg.dtype)
-    stages = tfm.stages_for(cfg)
-    new_caches = []
-    for st_params, st_cache, stage in zip(params["stages"], cache, stages):
-        if stage.repeats == 1:
-            nc = {}
-            for i, sp in enumerate(stage.block):
-                x, nc[f"l{i}"] = _decode_layer(
-                    st_params[f"l{i}"], st_cache[f"l{i}"], x, sp, cfg, index,
-                    pages)
-            new_caches.append(nc)
-        else:
-            def body(carry, inp, stage=stage):
-                xx = carry
-                lp, lc = inp
-                nc = {}
-                for i, sp in enumerate(stage.block):
-                    xx, nc[f"l{i}"] = _decode_layer(
-                        lp[f"l{i}"], lc[f"l{i}"], xx, sp, cfg, index, pages)
-                return xx, nc
-            x, nc = jax.lax.scan(body, x, (st_params, st_cache))
-            new_caches.append(nc)
+    x, new_caches = _run_layers(
+        params, cache, x, cfg,
+        lambda p, c, xx, sp, layer: _decode_layer(p, c, xx, sp, cfg, index,
+                                                  pages, layer))
     return _unembed(params, x, cfg), new_caches
 
 
@@ -545,15 +599,18 @@ def _ssd_prefill_chunk(p, x, cfg: ModelConfig, row: Dict[str, Array],
 
 
 def _chunk_layer(p, cache, x, spec: LayerSpec, cfg: ModelConfig,
-                 positions, start, slot, pages_row, first: bool):
+                 positions, start, slot, pages_row, first: bool,
+                 layer: Optional[Array] = None):
     """One layer of chunked prefill for one slot. x: [1, L, D].
 
     Full-attention K/V goes through the page pool (``paged_prefill_update``
     writes the chunk, ``paged_gather`` reads every earlier page back for
     the non-first chunks). SSD layers carve the slot's row out of the
-    per-slot state arrays, run ``_ssd_prefill_chunk`` and write it back —
-    ``slot`` stays a traced scalar so one compiled chunk serves all slots.
-    Only families ``chunk_tokens_for`` admits ever reach here."""
+    per-slot state arrays, run ``_ssd_prefill_chunk`` and write that row
+    back alone — ``slot`` stays a traced scalar so one compiled chunk
+    serves all slots. ``layer`` indexes stacked cache leaves inside the
+    layer scan (``_run_layers``). Only families ``chunk_tokens_for`` admits
+    ever reach here."""
     new_cache = dict(cache)
     if spec.ffn not in ("mlp", "kan", "none"):
         raise NotImplementedError(
@@ -567,7 +624,7 @@ def _chunk_layer(p, cache, x, spec: LayerSpec, cfg: ModelConfig,
                 k = layers.apply_rope(k, positions, cfg.rope_theta)
         with jax.named_scope("cache_update"):
             kp, vp = attn_lib.paged_prefill_update(cache["k"], cache["v"], k,
-                                                   v, pages_row, start)
+                                                   v, pages_row, start, layer)
         new_cache["k"], new_cache["v"] = kp, vp
         with jax.named_scope("mixer"):
             with jax.named_scope("paged_attention"):
@@ -577,8 +634,8 @@ def _chunk_layer(p, cache, x, spec: LayerSpec, cfg: ModelConfig,
                     o = attn_lib.chunked_attention(
                         q, k, v, causal=True, kv_chunk=cfg.attn_kv_chunk)
                 else:
-                    ck = attn_lib.paged_gather(kp, pages_row[None])
-                    cv = attn_lib.paged_gather(vp, pages_row[None])
+                    ck = attn_lib.paged_gather(kp, pages_row[None], layer)
+                    cv = attn_lib.paged_gather(vp, pages_row[None], layer)
                     o = attn_lib.chunked_attention(
                         q, ck, cv, causal=True, q_offset=start,
                         kv_valid_len=start + x.shape[1],
@@ -588,13 +645,12 @@ def _chunk_layer(p, cache, x, spec: LayerSpec, cfg: ModelConfig,
     elif spec.mixer == "ssd":
         with jax.named_scope("mixer"):
             xn = layers.NORM_APPLY[cfg.norm](p["mixer_norm"], x)
-            row = {k: jax.lax.dynamic_slice_in_dim(cache[k], slot, 1, axis=0)
+            row = {k: _rows(cache[k], layer, slot)
                    for k in ("state", "conv_buf")}
             y, rc = _ssd_prefill_chunk(p["ssd"], xn, cfg, row, first)
         with jax.named_scope("cache_update"):
             for k in ("state", "conv_buf"):
-                new_cache[k] = jax.lax.dynamic_update_slice_in_dim(
-                    cache[k], rc[k].astype(cache[k].dtype), slot, axis=0)
+                new_cache[k] = _set_rows(cache[k], rc[k], layer, slot)
         with jax.named_scope("mixer"):
             x = x + y.astype(x.dtype)
     else:
@@ -620,28 +676,11 @@ def prefill_chunk(params, cfg: ModelConfig, cache, tokens: Array,
     slot = jnp.asarray(slot)
     x = layers.embed_lookup(params["embed"], tokens).astype(cfg.dtype)
     positions = start + jnp.arange(tokens.shape[1])
-    stages = tfm.stages_for(cfg)
-    new_caches = []
-    for st_params, st_cache, stage in zip(params["stages"], cache, stages):
-        if stage.repeats == 1:
-            nc = {}
-            for i, sp in enumerate(stage.block):
-                x, nc[f"l{i}"] = _chunk_layer(
-                    st_params[f"l{i}"], st_cache[f"l{i}"], x, sp, cfg,
-                    positions, start, slot, pages_row, first)
-            new_caches.append(nc)
-        else:
-            def body(carry, inp, stage=stage):
-                xx = carry
-                lp, lc = inp
-                nc = {}
-                for i, sp in enumerate(stage.block):
-                    xx, nc[f"l{i}"] = _chunk_layer(
-                        lp[f"l{i}"], lc[f"l{i}"], xx, sp, cfg, positions,
-                        start, slot, pages_row, first)
-                return xx, nc
-            x, nc = jax.lax.scan(body, x, (st_params, st_cache))
-            new_caches.append(nc)
+    x, new_caches = _run_layers(
+        params, cache, x, cfg,
+        lambda p, c, xx, sp, layer: _chunk_layer(
+            p, c, xx, sp, cfg, positions, start, slot, pages_row, first,
+            layer))
     if not last:
         return jnp.zeros((1,), jnp.int32), new_caches
     logits = _unembed(params, x[:, -1:], cfg)

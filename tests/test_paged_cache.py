@@ -370,3 +370,129 @@ def test_rglru_scan_carried_state_matches_full_scan():
     h2 = rg.rglru_scan(params, u[:, 6:], h0=h1[:, -1])
     np.testing.assert_allclose(np.asarray(jnp.concatenate([h1, h2], axis=1)),
                                np.asarray(full), rtol=2e-5, atol=2e-6)
+
+
+# ---------------------------------------------------------------------------
+# the layer scan updates the stacked cache in place, with the same numbers
+# ---------------------------------------------------------------------------
+
+def _unrolled_layers(params, cache, x, cfg, layer_fn):
+    """``dec._run_layers`` without a scan: the stacked layers run one by one
+    in Python, each on its own slice of the stacked params and cache
+    (``layer`` None), and their caches are stacked again."""
+    out = []
+    for st_p, st_c, stage in zip(params["stages"], cache,
+                                 tfm.stages_for(cfg)):
+        reps = range(stage.repeats) if stage.repeats > 1 else [None]
+        per = []
+        for r in reps:
+            p_r, c_r = (st_p, st_c) if r is None else jax.tree.map(
+                lambda a, r=r: a[r], (st_p, st_c))
+            nc = {}
+            for i, sp in enumerate(stage.block):
+                x, nc[f"l{i}"] = layer_fn(p_r[f"l{i}"], c_r[f"l{i}"], x, sp,
+                                          None)
+            per.append(nc)
+        out.append(per[0] if stage.repeats == 1
+                   else jax.tree.map(lambda *a: jnp.stack(a), *per))
+    return x, out
+
+
+def _random_like(tree, seed):
+    leaves, tdef = jax.tree.flatten(tree)
+    keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
+    return tdef.unflatten([jax.random.normal(k, a.shape).astype(a.dtype)
+                           for k, a in zip(keys, leaves)])
+
+
+# slot 1 is idle: its page table is all garbage page, its index 0
+_PAGES = np.array([[1, 2, 3, 4, 9, 10], [0] * 6, [5, 6, 7, 8, 11, 12]],
+                  np.int32)
+_INDEX = np.array([13, 0, 20], np.int32)
+
+
+@pytest.mark.parametrize("case,arch_id", [
+    ("decode_ssd_masked_slot", "mamba2_1p3b"),
+    ("decode_paged_garbage_slot", "mistral_nemo_12b"),
+    ("decode_unpaged", "mistral_nemo_12b"),
+    ("decode_rolling", "mixtral_8x7b"),
+    ("decode_cross_read_only", "whisper_base"),
+    ("chunk_non_first_ssd", "mamba2_1p3b"),
+    ("chunk_non_first_paged", "mistral_nemo_12b")])
+def test_layer_scan_matches_unrolled_layers(case, arch_id, monkeypatch):
+    """The stacked-layer scan that carries the cache and writes each layer's
+    rows in place gives, bit for bit, the logits (or token) and every cache
+    leaf of the same layers run one by one on their own slices."""
+    m, params = _model(arch_id)
+    enc_len = 8 if m.family == "encdec" else 0
+    n_slots, max_len, ps = 3, 48, 8
+    if case == "decode_unpaged":
+        cache = dec.init_cache(m, n_slots, max_len)
+    else:
+        cache = dec.init_paged_cache(m, n_slots, max_len, page_size=ps,
+                                     n_pages=13, enc_len=enc_len)
+    cache = _random_like(cache, 1)
+    assert any(st.repeats > 1 for st in tfm.stages_for(m))
+    if case.startswith("decode"):
+        pages = None if case == "decode_unpaged" else jnp.asarray(_PAGES)
+        toks = jnp.asarray([[5], [7], [11]], jnp.int32)
+        if case == "decode_rolling":      # past the window: the ring wraps
+            assert m.window < 20
+            idx = jnp.asarray([m.window + 3, 0, m.window - 2], jnp.int32)
+        else:
+            idx = jnp.asarray(_INDEX)
+
+        def run():
+            return jax.jit(lambda p, c: dec.decode_step(
+                p, c, toks, idx, m, pages=pages))(params, cache)
+    else:
+        step = dec.chunk_tokens_for(m, ps)
+        toks = jnp.asarray(np.arange(1, step + 1)[None], jnp.int32)
+
+        def run():
+            return jax.jit(lambda p, c: dec.prefill_chunk(
+                p, m, c, toks, jnp.int32(step), jnp.int32(2),
+                jnp.asarray(_PAGES[2]), first=False, last=True))(params, cache)
+    got = run()
+    monkeypatch.setattr(dec, "_run_layers", _unrolled_layers)
+    want = run()
+    flat_got, tdef = jax.tree.flatten(got)
+    flat_want, tdef_want = jax.tree.flatten(want)
+    assert tdef == tdef_want
+    for g, w in zip(flat_got, flat_want):
+        assert g.dtype == w.dtype
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w))
+
+
+def test_paged_helpers_layer_index_matches_one_layer():
+    """``paged_cache_update``, ``paged_prefill_update`` and ``paged_gather``
+    with a ``layer`` index on stacked pools act as the unindexed forms on
+    that layer's pool, and leave every other layer as it was."""
+    from repro.models import attention as attn_lib
+
+    reps, n_pages, ps, kv, hd = 3, 9, 4, 2, 8
+    k0, v0 = _random_like(
+        (jnp.zeros((reps, n_pages, ps, kv, hd)),) * 2, 2)
+    pages = jnp.asarray([[1, 2, 3], [0, 0, 0], [4, 5, 6]], jnp.int32)
+    index = jnp.asarray([5, 0, 9], jnp.int32)
+    new = _random_like((jnp.zeros((3, 1, kv, hd)),) * 2, 3)
+    chunk = _random_like((jnp.zeros((1, 6, kv, hd)),) * 2, 4)
+    layer = jnp.int32(1)
+
+    def one_layer(stacked, per_layer):
+        return stacked.at[1].set(per_layer)
+
+    kp, vp = attn_lib.paged_cache_update(k0, v0, *new, pages, index, layer)
+    k1, v1 = attn_lib.paged_cache_update(k0[1], v0[1], *new, pages, index)
+    np.testing.assert_array_equal(kp, one_layer(k0, k1))
+    np.testing.assert_array_equal(vp, one_layer(v0, v1))
+
+    kp, vp = attn_lib.paged_prefill_update(k0, v0, *chunk, pages[2],
+                                           jnp.int32(4), layer)
+    k1, v1 = attn_lib.paged_prefill_update(k0[1], v0[1], *chunk, pages[2],
+                                           jnp.int32(4))
+    np.testing.assert_array_equal(kp, one_layer(k0, k1))
+    np.testing.assert_array_equal(vp, one_layer(v0, v1))
+
+    np.testing.assert_array_equal(attn_lib.paged_gather(k0, pages, layer),
+                                  attn_lib.paged_gather(k0[1], pages))

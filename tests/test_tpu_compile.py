@@ -1,4 +1,5 @@
-"""The Pallas kernels compile for a TPU v5e at real widths.
+"""The Pallas kernels compile for a TPU v5e at real widths, and the
+engine's serving programs compile for it without copying the cache.
 
 Nothing runs: each test lowers a kernel through its ``kernels.ops`` wrapper
 (the block picking and padding the serving path uses) with
@@ -8,13 +9,21 @@ aligned to the (8, 128) tiling, relayouts Mosaic cannot lower, kernels
 that do not fit VMEM. The compile cache stays off around these compiles
 (an entry written without a chip could not be read back).
 """
+import dataclasses
+import functools
+import re
+
 import jax
 import jax.numpy as jnp
 import pytest
 from jax.sharding import SingleDeviceSharding
 
+from repro.configs import get_arch
 from repro.core.quant import ASPConfig
 from repro.kernels import ops
+from repro.models import transformer as tfm
+from repro.serve import decode as dec
+from repro.serve import engine as engine_lib
 
 
 @pytest.fixture(scope="module")
@@ -99,3 +108,64 @@ def test_cim_mac_tiled_compiles_for_v5e(compiled_for):
     c = compiled_for(tiled, ((8, r), jnp.float32), ((r, c_out), jnp.int8),
                      ((r,), jnp.float32), ((r, c_out), jnp.float32))
     assert "tpu_custom_call" in c.as_text()
+
+
+def _ops(hlo: str, op: str):
+    """Result shapes of every ``op`` in an HLO text, fused ones included."""
+    return [tuple(int(d) for d in m.group(1).split(",") if d)
+            for m in re.finditer(rf"= \w+\[([\d,]*)\]\{{[^}}]*\}} {op}\(",
+                                 hlo)]
+
+
+# the benchmark's cells: (arch, layers kept, slots, max_len), page 64
+SERVING_CELLS = {"mamba2_1p3b": ("mamba2_1p3b", None, 32, 2560),
+                 "mistral_nemo_12b_4l": ("mistral_nemo_12b", 4, 32, 3072)}
+
+
+@pytest.mark.parametrize("cell", sorted(SERVING_CELLS))
+def test_serving_programs_update_stacked_cache_in_place(cell, one_chip):
+    """The engine's decode tick and each kind of chunk program (first,
+    middle, last, whole prompt), built for a v5e at the benchmark's widths:
+    no ``copy`` of a stacked cache leaf (the whole SSD state or K/V pool,
+    copied per call when the layer scan took the cache as ``xs`` and gave
+    it back as ``ys``), and no ``dynamic-slice`` of one layer's whole pool
+    in the decode tick. (At SMOKE widths the compiler moves the tiny cache
+    into on-chip memory and back, which says nothing of this.)"""
+    arch_id, n_layers, slots, max_len = SERVING_CELLS[cell]
+    m = get_arch(arch_id).model
+    if n_layers:
+        m = dataclasses.replace(m, n_layers=n_layers)
+    ps = 64
+    p = -(-max_len // ps)
+
+    def chip(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+    params, cache = jax.tree.map(
+        lambda a: chip(a.shape, a.dtype), jax.eval_shape(lambda: (
+            tfm.init_model(jax.random.PRNGKey(0), m),
+            dec.init_paged_cache(m, slots, max_len, page_size=ps,
+                                 n_pages=slots * p + 1))))
+    programs = {"decode_tick": engine_lib._named_jit(
+        functools.partial(engine_lib._decode_fn, cfg=m), "decode_tick",
+        donate_argnums=1).lower(params, cache, chip((slots,)),
+                                chip((slots,)), chip((slots, p)))}
+    c = dec.chunk_tokens_for(m, ps)
+    for key in [(c, True, False), (c, False, False), (c // 2, False, True),
+                (c, True, True)]:
+        length, first, last = key
+        programs[key] = engine_lib._named_jit(
+            functools.partial(engine_lib._chunk_fn, cfg=m, first=first,
+                              last=last), engine_lib._chunk_jit_name(key),
+            donate_argnums=1).lower(params, cache, chip((1, length)),
+                                    chip(()), chip(()), chip((p,)))
+    stacked = {leaf.shape for st, stage in zip(cache, tfm.stages_for(m))
+               if stage.repeats > 1 for leaf in jax.tree.leaves(st)}
+    assert stacked
+    one_layer = {s[1:] for s in stacked} | {(1,) + s[1:] for s in stacked}
+    for name, low in programs.items():
+        hlo = low.compile().as_text()
+        copies = [s for s in _ops(hlo, "copy") if s in stacked]
+        assert not copies, (name, copies)
+        if name == "decode_tick" and m.family != "ssm":
+            slices = [s for s in _ops(hlo, "dynamic-slice") if s in one_layer]
+            assert not slices, (name, slices)
