@@ -18,6 +18,11 @@ def layer_params(m) -> int:
     return attn + 3 * d * m["d_ff"] + 2 * d
 
 
+def weight_bytes(m) -> int:
+    """The least bytes of the weights that one pass reads."""
+    return WEIGHT_BYTES * param_count(m)
+
+
 def param_count(m) -> int:
     embed = m["vocab"] * m["d_model"] * (1 if m["tie_embeddings"] else 2)
     return m["n_layers"] * layer_params(m) + embed + m["d_model"]
@@ -44,7 +49,7 @@ def decode_tick(m, n_tok: int, ctx_sum: int):
     flops = (n_tok * (L * _matmul_flops(m) + unembed_flops(m))
              + L * _attn_flops(m, ctx_sum))
     kv_token = 2 * m["n_kv_heads"] * m["head_dim"] * KV_BYTES * L
-    nbytes = WEIGHT_BYTES * param_count(m) + kv_token * ctx_sum
+    nbytes = weight_bytes(m) + kv_token * ctx_sum
     return flops, nbytes
 
 

@@ -25,6 +25,11 @@ def layer_params(m) -> int:
     return proj + k * (di + 2 * n) + 3 * h + di + d
 
 
+def weight_bytes(m) -> int:
+    """The least bytes of the weights that one pass reads."""
+    return WEIGHT_BYTES * param_count(m)
+
+
 def param_count(m) -> int:
     return (m["n_layers"] * layer_params(m) + m["vocab"] * m["d_model"]
             + m["d_model"])
@@ -48,7 +53,7 @@ def decode_tick(m, n_tok: int, ctx_sum: int):
     d, di, n, p, h, k = _sizes(m)
     flops = n_tok * (m["n_layers"] * _token_flops(m) + unembed_flops(m))
     state = (h * p * n * STATE_BYTES + (k - 1) * (di + 2 * n) * CONV_BYTES)
-    nbytes = (WEIGHT_BYTES * param_count(m)
+    nbytes = (weight_bytes(m)
               + 2 * n_tok * m["n_layers"] * state)
     return flops, nbytes
 

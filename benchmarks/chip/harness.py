@@ -127,11 +127,14 @@ def init_params(cfg, seed: int):
     return params
 
 
-def make_engine(cell: Cell, params, cfg):
+def make_engine(cell: Cell, params, cfg, recorder=None):
+    """The cell's engine; ``recorder`` (a ``repro.obs.EngineRecorder``)
+    marks its phases in the profiler's trace."""
     from repro.serve.engine import Engine
     geo = cell.mix["engine"]
     return Engine(params, cfg, n_slots=geo["n_slots"], max_len=geo["max_len"],
-                  page_size=cell.conf.get("engine", {}).get("page_size", 64))
+                  page_size=cell.conf.get("engine", {}).get("page_size", 64),
+                  recorder=recorder)
 
 
 def chunk_keys(length: int, start: int, chunk: int):

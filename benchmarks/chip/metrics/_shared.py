@@ -13,6 +13,24 @@ def program_ms(ctx, program: str):
     return p["device_s"] / p["calls"] * 1e3
 
 
+def scope_ms(ctx, program: str, scope: str):
+    """Device milliseconds per call of ``program`` in ops under the model
+    step's ``scope`` (one of ``trace_reduce.SCOPES``, or ``unscoped``),
+    from each op's own time; None where the trace holds no scoped call."""
+    if not ctx.trace:
+        return None
+    return ctx.trace.get("scope_ms", {}).get(program, {}).get(scope)
+
+
+def named_ms(ctx, program: str, name: str):
+    """Device milliseconds per call of ``program`` in ops whose name path
+    holds the segment ``name`` (a ``jax.named_scope`` at any depth, such as
+    ``kan_ffn`` inside ``ffn``); None where no op of a call does."""
+    if not ctx.trace:
+        return None
+    return ctx.trace.get("by_name", {}).get(program, {}).get(name)
+
+
 def decode_roofline(ctx):
     """Least time of the window's decode ticks, from their shapes, over
     their device time, in percent: max(FLOPs / peak, least bytes / HBM

@@ -47,6 +47,14 @@ def init_layer(key, m):
     }
 
 
+def program_layer(tree):
+    """The program's one-layer parameter tree under ``init_layer``'s names
+    (the MLP's ``wo`` is ``wo2`` here), for the tests that check both draw
+    the same weights."""
+    return {**tree["attn"],
+            **{("wo2" if k == "wo" else k): v for k, v in tree["mlp"].items()}}
+
+
 def rope(x, theta):
     """x [T, H, hd]: rotate the two halves by position * theta^(-i/half)."""
     t, _, hd = x.shape

@@ -47,6 +47,12 @@ def init_layer(key, m):
     }
 
 
+def program_layer(tree):
+    """The program's one-layer parameter tree under ``init_layer``'s names,
+    for the tests that check both draw the same weights."""
+    return dict(tree["ssd"])
+
+
 def ssd_scan(xs, dt, a, b, c):
     """xs [T,H,P], dt [T,H], a [H], b/c [T,N] -> y [T,H,P] (no skip)."""
     t, h, p = xs.shape

@@ -36,6 +36,7 @@ from typing import List
 sys.path.insert(0, str(Path(__file__).resolve().parent))
 
 import harness as hz            # noqa: E402
+import trace_phases             # noqa: E402
 import trace_reduce             # noqa: E402
 import traffic as traffic_lib   # noqa: E402
 from peaks import peaks_for     # noqa: E402
@@ -134,7 +135,13 @@ def serve(cell: hz.Cell, seed: int, seconds: float, trace: bool) -> Ctx:
                                   ramp + seconds + hz.DRAIN_S,
                                   window=(ramp, seconds))
     params = hz.init_params(cfg, seed)
-    eng = hz.make_engine(cell, params, cfg)
+    recorder = None
+    if trace:
+        # the engine's phases as profiler spans; untraced runs, which give
+        # every end-to-end metric, run the engine without one
+        from repro.obs import EngineRecorder
+        recorder = EngineRecorder()
+    eng = hz.make_engine(cell, params, cfg, recorder)
     n_warm = hz.warm_up(eng, traffic.prompt_lengths(), cfg.vocab)
     compiles = hz.Compiles()
     gc_pauses = hz.GcPauses()
@@ -269,13 +276,14 @@ def run_cell(cell: hz.Cell, seed: int, seconds: float, trace: bool) -> dict:
 
 
 def reduce_trace(ticks):
-    """Reduce the trace just written, with the harness's ticks (start and
-    end relative to the window span, chunks run, decoded) naming the
-    engine's programs; then delete it."""
+    """Reduce the trace just written (``trace_phases.reduce``: busy time,
+    programs, the engine's phases and the model step's scopes), with the
+    harness's ticks (start and end relative to the window span, chunks run,
+    decoded) naming the engine's programs; then delete it."""
     paths = sorted(TRACE_DIR.rglob("*.xplane.pb"))
     if not paths:
         return None
-    red = trace_reduce.reduce(trace_reduce.load(str(paths[-1])), ticks)
+    red = trace_phases.reduce(trace_phases.load(str(paths[-1])), ticks)
     shutil.rmtree(TRACE_DIR, ignore_errors=True)
     return red
 

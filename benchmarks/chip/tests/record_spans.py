@@ -1,24 +1,21 @@
-"""Run one cell traced with the engine's own spans, and reduce its phases
-and scopes (run it on a machine with the chip):
+"""Run one cell traced, as ``run.py --trace 1`` runs it (the engine's
+spans and the model step's scopes on), and keep what its reduction read
+(run it on a machine with the chip):
 
     python benchmarks/chip/tests/record_spans.py --workload <name> \\
         --seed <n> --seconds <s> --out <dir>
 
-It runs the cell as ``run.py --trace 1`` does, but builds the engine with a
-``repro.obs.EngineRecorder`` where the program's recorder leaves the jits
-alone (a program whose recorder still swaps them for AOT profilers runs
-without one, as ``run.py`` runs it). Beside the usual result line it prints
-and writes ``phases.json`` (``trace_phases.reduce``: idle time by engine
-phase, ``host_gap_ms``, device ms per call by scope), ``op_stats.json``
-(the metadata stats of the first device ops, to show which carries the
-scope path) and ``excerpt.json`` (the first ``--excerpt-ms`` of the window
-in ``trace_phases.load``'s form, op names cut to their label, for the CPU
-tests).
+Beside the usual result line it prints and writes ``phases.json``
+(``trace_phases.reduce`` less the breakdown: idle time by engine phase,
+``host_gap_ms``, device ms per call by scope and by name),
+``op_stats.json`` (the metadata stats of the first device ops, to show
+which carries the scope path) and ``excerpt.json`` (the first
+``--excerpt-ms`` of the window in ``trace_phases.load``'s form, op names
+cut to their label, for the CPU tests).
 """
 from __future__ import annotations
 
 import argparse
-import importlib
 import json
 import shutil
 import sys
@@ -30,16 +27,6 @@ import harness as hz        # noqa: E402
 import run                  # noqa: E402
 import trace_phases         # noqa: E402
 import trace_reduce         # noqa: E402
-
-
-def recorder_leaves_jits() -> bool:
-    """False where ``repro.obs.profile.JitProfiler`` exists: that
-    recorder swaps every engine jit for an AOT profiler."""
-    try:
-        profile = importlib.import_module("repro.obs.profile")
-    except ImportError:
-        return True
-    return not hasattr(profile, "JitProfiler")
 
 
 def excerpt(ev: dict, ticks, ms: float) -> dict:
@@ -79,21 +66,8 @@ def main() -> int:
     args = ap.parse_args()
     out = Path(args.out)
     out.mkdir(parents=True, exist_ok=True)
-    hz.import_program()
-    spans = recorder_leaves_jits()
 
-    make_engine = hz.make_engine
-
-    def make_recorded_engine(cell, params, cfg):
-        eng = make_engine(cell, params, cfg)
-        if spans:
-            # the same as Engine(..., recorder=...): that recorder leaves
-            # the engine's jits as they are
-            from repro.obs import EngineRecorder
-            eng.obs = EngineRecorder(trace_capacity=4096)
-        return eng
-
-    def reduce_both(ticks):
+    def reduce_and_keep(ticks):
         path = str(sorted(run.TRACE_DIR.rglob("*.xplane.pb"))[-1])
         (out / "op_stats.json").write_text(
             json.dumps(op_stat_sample(path), indent=1))
@@ -102,19 +76,15 @@ def main() -> int:
         if args.excerpt_ms:
             (out / "excerpt.json").write_text(
                 json.dumps(excerpt(ev, ticks, args.excerpt_ms)))
-        phases = trace_phases.reduce(ev) or {}
-        for dev in ev["devices"]:
-            dev["ops"] = [op[:3] for op in dev["ops"]]
-        red = trace_reduce.reduce(ev, ticks)
+        red = trace_phases.reduce(ev, ticks)
+        phases = {k: v for k, v in (red or {}).items() if k != "breakdown"}
         phases["reduce_s"] = hz.clock() - t0
-        phases["engine_spans"] = spans
         (out / "phases.json").write_text(json.dumps(phases, indent=1))
         print(json.dumps({"phases": phases}), flush=True)
         shutil.rmtree(run.TRACE_DIR, ignore_errors=True)
         return red
 
-    hz.make_engine = make_recorded_engine
-    run.reduce_trace = reduce_both
+    run.reduce_trace = reduce_and_keep
     res = run.run_cell(hz.load_cell(args.workload), args.seed, args.seconds,
                        True)
     print(json.dumps(res))

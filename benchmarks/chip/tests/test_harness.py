@@ -12,6 +12,7 @@ import control
 import harness as hz
 import peaks
 import run
+import smoke
 from smoke import smoke_cell
 
 RUN = str(hz.BENCH / "run.py")
@@ -71,8 +72,15 @@ def test_refuses_a_checkout_without_the_program(tmp_path):
 
 @pytest.mark.parametrize("name", CELLS)
 def test_smoke_run_is_correct_and_compiles_nothing_in_the_window(
-        name, capsys, cpu_as_chip):
+        name, capsys, cpu_as_chip, monkeypatch):
+    make_engine, recorders = hz.make_engine, []
+
+    def spy(cell, params, cfg, recorder=None):
+        recorders.append(recorder)
+        return make_engine(cell, params, cfg, recorder)
+    monkeypatch.setattr(hz, "make_engine", spy)
     out = run.run_cell(smoke_cell(name), 2**31 + 3, 2.0, False)
+    assert recorders == [None]      # untraced: the engine as users run it
     logged = [json.loads(line) for line in capsys.readouterr().out.splitlines()
               if line.startswith("{")]
     assert out["correct"], out["checks"]
@@ -116,3 +124,50 @@ def test_fp8_control_in_the_programs_place_is_not_correct(name, cpu_as_chip):
     assert r["program_correct"], r
     assert not r["control_correct"], r
     assert r["control_gap"] > r["limit"] > r["program_gap"]
+
+
+@pytest.mark.parametrize("key,path", [
+    ("smoke_model", "configs/mistral_nemo_12b_4l.json"),
+    ("smoke", "traffic/offline.json")])
+def test_a_file_without_its_smoke_block_is_an_error_naming_it(
+        key, path, monkeypatch):
+    cell = hz.load_cell("mistral_nemo_12b_4l.offline")
+    part = cell.conf if key == "smoke_model" else cell.mix
+    del part[key]
+    monkeypatch.setattr(hz, "load_cell", lambda name: cell)
+    with pytest.raises(KeyError, match=path):
+        smoke.smoke_cell(cell.name)
+
+
+def test_a_new_configuration_and_cell_join_as_files_and_entries(tmp_path):
+    """A copy of the benchmark gains a configuration (a renamed copy of
+    mistral_nemo_12b_4l's file) and a cell on the offline mix, as new
+    files and new BENCHMARK.json entries alone: the copy's own reference
+    and count tests take it as a case, and pass."""
+    root = tmp_path / "checkout"
+    bench = root / "benchmarks" / "chip"
+    shutil.copytree(hz.BENCH, bench,
+                    ignore=shutil.ignore_patterns("__pycache__"))
+    conf = json.loads((bench / "configs/mistral_nemo_12b_4l.json").read_text())
+    conf["name"] = "nemo_copy"
+    (bench / "configs/nemo_copy.json").write_text(json.dumps(conf))
+    spec = json.loads((hz.ROOT / "BENCHMARK.json").read_text())
+    old = next(c for c in spec["configs"]
+               if c["name"] == "mistral_nemo_12b_4l")
+    spec["configs"].append(dict(old, name="nemo_copy",
+                                file="benchmarks/chip/configs/nemo_copy.json"))
+    spec["workloads"].append({"name": "nemo_copy.offline",
+                              "config": "nemo_copy", "traffic": "offline",
+                              "chips": 1, "why": "a copy"})
+    (root / "BENCHMARK.json").write_text(json.dumps(spec))
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=str(hz.ROOT / "src"))
+    proc = subprocess.run(
+        [sys.executable, "-m", "pytest", "-q", "-p", "no:cacheprovider",
+         "-p", "no:xdist", "-k", "nemo_copy",
+         str(bench / "tests/test_reference.py"),
+         str(bench / "tests/test_counts.py")],
+        cwd=root, env=env, capture_output=True, text=True, timeout=600)
+    assert proc.returncode == 0, proc.stdout[-3000:] + proc.stderr[-2000:]
+    assert "6 passed" in proc.stdout, proc.stdout[-1000:]
+    assert not (hz.BENCH / "configs/nemo_copy.json").exists()

@@ -1,4 +1,5 @@
 """Metric readers on a hand-made context."""
+import pytest
 
 import harness as hz
 import run
@@ -41,3 +42,10 @@ def test_every_metric_of_the_benchmark_has_a_reader():
     bench = hz.load_cell("mamba2_1p3b.chat_burst").bench
     for m in bench["end_to_end"] + bench["per_layer"]:
         assert callable(hz.load_module("metrics", m["name"]).read), m
+
+
+@pytest.mark.parametrize("name", [
+    "host_gap_ms", "decode_mixer_ms", "decode_ffn_ms",
+    "decode_cache_update_ms", "decode_unscoped_ms"])
+def test_phase_and_scope_metrics_read_nothing_without_a_trace(name):
+    assert hz.load_module("metrics", name).read(_ctx(None)) is None

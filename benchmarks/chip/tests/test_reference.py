@@ -6,9 +6,9 @@ import numpy as np
 import pytest
 
 import harness as hz
-from smoke import smoke_cell
+from smoke import config_cells, smoke_cell
 
-CELLS = ["mamba2_1p3b.chat_burst", "mistral_nemo_12b_4l.sessions"]
+CELLS = config_cells()
 SEED = 2**31 + 17
 
 
@@ -29,10 +29,7 @@ def test_reference_weights_are_the_programs(name):
     stacked = params["stages"][0]["l0"]
     for i, k in enumerate(layer_keys):
         w = ref.init_layer(k, cell.model)
-        prog = jax.tree.map(lambda x: x[i], stacked)
-        flat = {**prog.get("ssd", {}), **prog.get("attn", {}),
-                **{("wo2" if k2 == "wo" else k2): v
-                   for k2, v in prog.get("mlp", {}).items()}}
+        flat = ref.program_layer(jax.tree.map(lambda x: x[i], stacked))
         compared = 0
         for key, v in w.items():
             if getattr(flat.get(key), "ndim", 0) > 1:

@@ -169,3 +169,62 @@ def test_recorded_excerpt_with_engine_spans_and_scopes():
     assert {"mixer", "cache_update", "ffn", "unembed"} <= set(dec)
     parts = sum(v for k, v in dec.items() if k not in ("calls", "device_ms"))
     assert parts == pytest.approx(dec["device_ms"], rel=0.01)
+
+
+def test_run_reduces_a_traced_run_with_phases_and_scopes(tmp_path,
+                                                         monkeypatch):
+    """``run.reduce_trace`` on the recorded excerpt: the trace's programs and
+    busy time as before, and beside them ``host_gap_ms``, the scope
+    partition of a decode call and its device ms by name; idle gaps named
+    by engine phase and op labels by scope; the trace is deleted."""
+    import run
+    ev = json.loads((DATA / "trace_excerpt_spans.json").read_text())
+    trace_dir = tmp_path / "trace"
+    (trace_dir / "plugins").mkdir(parents=True)
+    (trace_dir / "plugins" / "x.xplane.pb").write_bytes(b"")
+    monkeypatch.setattr(run, "TRACE_DIR", trace_dir)
+    monkeypatch.setattr(tp, "load", lambda path: ev)
+    red = run.reduce_trace(ev["ticks"])
+    assert not trace_dir.exists()
+    assert red["programs"]["decode"]["calls"] >= 2
+    assert 3.0 < red["host_gap_ms"] < 6.0
+    dec = red["scope_ms"]["decode"]
+    assert set(dec) == {"calls", "device_ms", *tp.SCOPES, "unscoped"}
+    parts = sum(dec[k] for k in (*tp.SCOPES, "unscoped"))
+    assert parts == pytest.approx(dec["device_ms"], rel=0.01)
+    names = red["by_name"]["decode"]
+    for scope in tp.SCOPES:
+        assert names.get(scope, 0.0) == pytest.approx(dec[scope])
+    assert 0 < names["paged_attention"] < names["mixer"]
+    gaps = {g[0] for g in red["breakdown"]["idle_gaps"]}
+    assert gaps & {"engine.readback", "engine.inputs", "engine.prefill_chunk"}
+    labels = [k for k, _ in red["breakdown"]["device_ops"]]
+    assert all(k.split(":")[0].split("/")[-1] in (*tp.SCOPES, "unscoped")
+               for k in labels), labels
+    assert {"decode/unscoped", "decode/unembed"} <= {
+        k.split(":")[0] for k in labels}, labels
+
+
+def test_a_metric_reads_a_nested_scope_by_name(tmp_path):
+    """A metric that a later PR adds as a file of its own reads a scope
+    outside ``SCOPES`` (``kan_ffn`` inside ``ffn``) through ``by_name``."""
+    import importlib.util
+    import harness as hz
+    import run
+    ev = _trace()
+    ops = ev["devices"][0]["ops"]
+    ops[4][3] = ops[4][3].replace("ffn/", "ffn/kan_ffn/")
+    ctx = run.Ctx(trace=tp.reduce(ev))
+    hz.load_module("metrics", "decode_ffn_ms")          # puts _shared on the path
+    path = tmp_path / "decode_kan_ffn_ms.py"
+    path.write_text("from _shared import named_ms\n\n\n"
+                    "def read(ctx):\n"
+                    "    return named_ms(ctx, 'decode', 'kan_ffn')\n")
+    spec = importlib.util.spec_from_file_location("decode_kan_ffn_ms", path)
+    metric = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(metric)
+    assert metric.read(ctx) == pytest.approx(1.5)
+    assert hz.load_module("metrics", "decode_ffn_ms").read(ctx) == \
+        pytest.approx(1.5)
+    assert tp.reduce(_trace())["by_name"]["decode"].get("kan_ffn") is None
+    assert metric.read(run.Ctx(trace=None)) is None

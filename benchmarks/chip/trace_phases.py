@@ -5,20 +5,25 @@ most of it and assumes the spans are disjoint. An engine built with a
 recorder (``repro.obs.EngineRecorder``) marks the phases of its tick as
 ``engine.<phase>`` spans nested inside the harness's ``engine.step``, and
 its programs carry ``jax.named_scope`` paths in their op metadata. This
-module reads both:
+module reads both, and its ``reduce`` is the reduction a traced run uses:
 
 * ``load``: ``trace_reduce.load``'s lists, each device op with a fourth
   field, the op's name path. ``jax.profiler.ProfileData`` shows an
   event's own stats only, and a TPU op's path is a stat of its event
-  metadata, so ``op_paths`` reads those from the ``.xplane.pb`` itself.
+  metadata, so ``op_stats`` reads those from the ``.xplane.pb`` itself.
 * ``attribute``: the innermost span under which most of an idle gap lies.
-* ``reduce``: idle seconds by innermost host span (exact, instant by
-  instant), the gap labels, ``host_gap_ms`` (device-idle ms per engine step
-  under the engine's own phase spans; ``engine.step``'s remainder is the
-  harness's and is left out) and device ms per call of each program by
+* ``reduce``: ``trace_reduce.reduce``'s numbers (busy, programs, the
+  breakdown's device ops, labelled by scope), with the breakdown's idle
+  gaps labelled by innermost span instead; beside them idle seconds by
+  innermost host span (exact, instant by instant), ``host_gap_ms``
+  (device-idle ms per engine step under the engine's own phase spans;
+  ``engine.step``'s remainder is the harness's and is left out) and device
+  ms per call of each program from each op's own time (its duration less
+  that of the ops it contains, as a loop holds its body): ``scope_ms`` by
   scope (``mixer``, ``cache_update``, ``ffn``, ``unembed``, else
-  ``unscoped``), from each op's own time (its duration less that of the
-  ops it contains, as a loop holds its body).
+  ``unscoped``), a partition, and ``by_name`` by every segment of the ops'
+  name paths, so that a scope nested in another (``kan_ffn`` in ``ffn``)
+  or added later is read by name.
 
 Everything reads None or empty where the program has no such span or scope.
 """
@@ -29,18 +34,12 @@ import re
 from typing import Dict, List, Optional, Tuple
 
 import trace_reduce as tr
+from trace_reduce import SCOPES, scope_of
 
-SCOPES = ("mixer", "cache_update", "ffn", "unembed")
 #: stats of a device op's metadata that may carry its name path
 SCOPE_STATS = ("tf_op",)
 STEP_SPAN = "engine.step"
-_SCOPE = re.compile(r"(?:^|/)(" + "|".join(SCOPES) + r")(?:/|$)")
-
-
-def scope_of(path: str) -> str:
-    """The first of ``SCOPES`` on an op's name path, else ``unscoped``."""
-    m = _SCOPE.search(path or "")
-    return m.group(1) if m else "unscoped"
+_OP_TYPE = re.compile(r":[^/]*$")     # ``name:OpType`` at a path's end
 
 
 def op_path(stats: Dict[str, str]) -> str:
@@ -51,7 +50,7 @@ def op_path(stats: Dict[str, str]) -> str:
         if k in stats:
             return stats[k]
     return next((v for v in stats.values()
-                 if v.startswith("jit(") or _SCOPE.search(v)), "")
+                 if v.startswith("jit(") or scope_of(v) != "unscoped"), "")
 
 
 def _varint(buf, i: int):
@@ -230,14 +229,22 @@ def program_role(module: str) -> Optional[str]:
     return None
 
 
-def scope_ms(ev: dict, lo: float, hi: float) -> Dict[str, Dict[str, float]]:
+def _path(op) -> str:
+    return op[3] if len(op) > 3 else ""
+
+
+def scope_ms(ev: dict, lo: float, hi: float):
     """Device ms per call of each engine program that ran wholly inside
-    [lo, hi], by scope, from its ops' own time; ``calls`` and
-    ``device_ms`` (module time per call) beside. Empty where no op carries
-    a path (the program has no scopes)."""
-    out: Dict[str, Dict[str, float]] = {}
-    if not any(op[3] for d in ev["devices"] for op in d["ops"]):
-        return out
+    [lo, hi], from its ops' own time, as ``(by_scope, by_name)``.
+    ``by_scope[role]`` partitions it over ``SCOPES`` and ``unscoped``
+    (every key present), with ``calls`` and ``device_ms`` (module time per
+    call) beside; ``by_name[role]`` holds each segment of the ops' name
+    paths, an op counted once in every segment on its path. Both are empty
+    where no op carries a path (the program has no scopes)."""
+    by_scope: Dict[str, Dict[str, float]] = {}
+    by_name: Dict[str, Dict[str, float]] = {}
+    if not any(_path(op) for d in ev["devices"] for op in d["ops"]):
+        return by_scope, by_name
     n_dev = len(ev["devices"])
     for dev in ev["devices"]:
         mods = sorted(dev["modules"], key=lambda m: m[1])
@@ -246,27 +253,38 @@ def scope_ms(ev: dict, lo: float, hi: float) -> Dict[str, Dict[str, float]]:
                  else None for name, start, dur in mods]
         for (_, _, dur), role in zip(mods, roles):
             if role:
-                r = out.setdefault(role, {"calls": 0.0, "device_ms": 0.0})
+                r = by_scope.setdefault(role, dict.fromkeys(
+                    ("calls", "device_ms", *SCOPES, "unscoped"), 0.0))
                 r["calls"] += 1 / n_dev
                 r["device_ms"] += dur * 1e-6 / n_dev
         ops = sorted(dev["ops"], key=lambda o: (o[1], -o[2]))
         for op, own in zip(ops, own_time(ops)):
             i = bisect.bisect_right(mstart, op[1]) - 1
-            if i >= 0 and roles[i]:
-                r = out[roles[i]]
-                key = scope_of(op[3])
-                r[key] = r.get(key, 0.0) + own * 1e-6 / n_dev
-    for r in out.values():
-        for k in list(r):
+            if i < 0 or not roles[i]:
+                continue
+            ms = own * 1e-6 / n_dev
+            path = _path(op)
+            by_scope[roles[i]][scope_of(path)] += ms
+            names = by_name.setdefault(roles[i], {})
+            for seg in set(_OP_TYPE.sub("", path).split("/")) - {""}:
+                names[seg] = names.get(seg, 0.0) + ms
+    for role, r in by_scope.items():
+        calls = r["calls"]
+        for k in r:
             if k != "calls":
-                r[k] /= r["calls"]
-    return out
+                r[k] /= calls
+        for k in by_name.get(role, {}):
+            by_name[role][k] /= calls
+    return by_scope, by_name
 
 
-def reduce(ev: dict, top: int = 10) -> Optional[dict]:
-    """Idle time by innermost host span, ``host_gap_ms`` and the scopes'
-    device ms per call; None when no device ran."""
-    if not ev["devices"]:
+def reduce(ev: dict, ticks=None, top: int = 10) -> Optional[dict]:
+    """``trace_reduce.reduce(ev, ticks, top)`` with the breakdown's idle
+    gaps labelled by innermost span, and beside it idle time by innermost
+    host span, ``host_gap_ms`` and the programs' device ms per call by
+    scope and by name; None when no device ran."""
+    red = tr.reduce(ev, ticks, top)
+    if red is None:
         return None
     win = [h for h in ev["host"] if h[0] == tr.WINDOW_SPAN]
     if win:
@@ -279,7 +297,7 @@ def reduce(ev: dict, top: int = 10) -> Optional[dict]:
     idle: Dict[str, float] = {}
     gaps = []
     for dev in ev["devices"]:
-        events = [e[:3] for e in dev["ops"] or dev["modules"]]
+        events = dev["ops"] or dev["modules"]
         busy = tr.union([[a, b] for _, _, a, b in tr._clip(events, lo, hi)])
         edges = [lo] + [x for iv in busy for x in iv] + [hi]
         for a, b in zip(edges[::2], edges[1::2]):
@@ -292,9 +310,11 @@ def reduce(ev: dict, top: int = 10) -> Optional[dict]:
     n_dev = len(ev["devices"])
     idle = {k: v / n_dev for k, v in idle.items()}
     gaps.sort(key=lambda g: -g[1])
+    red["breakdown"]["idle_gaps"] = gaps[:top]
     steps = sum(1 for h in host.spans if h[0] == STEP_SPAN and lo <= h[1] < hi)
     phase_s = sum(v for k, v in idle.items() if _is_phase(k))
-    return {
+    by_scope, by_name = scope_ms(ev, lo, hi)
+    red.update({
         "idle_by_span": idle,
         "idle_gaps": gaps[:top],
         "steps": steps,
@@ -303,8 +323,10 @@ def reduce(ev: dict, top: int = 10) -> Optional[dict]:
         # phase takes: instant by instant, and by whole gap labels
         "phase_share": _phase_share(idle.items()),
         "phase_share_gaps": _phase_share(gaps),
-        "scope_ms": scope_ms(ev, lo, hi),
-    }
+        "scope_ms": by_scope,
+        "by_name": by_name,
+    })
+    return red
 
 
 def _is_phase(name: str) -> bool:

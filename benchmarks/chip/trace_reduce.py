@@ -16,6 +16,9 @@ lists; ``reduce`` works on those lists alone, so a small recorded trace
   fingerprint takes the role most of its calls had (``decode``,
   ``prefill_chunk``); a module no tick claims keeps its own name without
   ``jit_`` and the fingerprint.
+* device ops: own label ``<program>: <op>``, or ``<program>/<scope>: <op>``
+  where the op carries its name path (``trace_phases.load``), the scope
+  being the first of ``SCOPES`` on the path, else ``unscoped``.
 * idle gaps: each gap between busy intervals is put down to the harness
   span (``harness.*``, ``engine.step``) that covers most of it.
 """
@@ -27,8 +30,17 @@ from typing import Dict, List, Optional
 
 WINDOW_SPAN = "harness.window"
 HOST_PREFIXES = ("harness.", "engine.")
+#: the model step's ``jax.named_scope`` names that partition its ops
+SCOPES = ("mixer", "cache_update", "ffn", "unembed")
+_SCOPE = re.compile(r"(?:^|/)(" + "|".join(SCOPES) + r")(?:/|$)")
 _SUFFIX = re.compile(r"\(\d+\)$")
 _LAYOUT = re.compile(r"\{[^{}]*\}")
+
+
+def scope_of(path: str) -> str:
+    """The first of ``SCOPES`` on an op's name path, else ``unscoped``."""
+    m = _SCOPE.search(path or "")
+    return m.group(1) if m else "unscoped"
 
 
 def program_name(module: str) -> str:
@@ -75,7 +87,7 @@ def union(intervals: List[List[float]]) -> List[List[float]]:
 
 
 def _clip(events, lo, hi):
-    for name, start, dur in events:
+    for name, start, dur, *_ in events:
         a, b = max(start, lo), min(start + dur, hi)
         if b > a:
             yield name, start, a, b
@@ -162,13 +174,18 @@ def reduce(ev: dict, ticks=None, top: int = 10) -> Optional[dict]:
         mods = sorted(dev["modules"], key=lambda m: m[1])
         mstart = [m[1] for m in mods]
         labels: Dict[str, str] = {}
-        for name, start, a, b in _clip(dev["ops"], lo, hi):
+        for name, start, dur, *path in dev["ops"]:
+            a, b = max(start, lo), min(start + dur, hi)
+            if b <= a:
+                continue
             i = bisect.bisect_right(mstart, start) - 1
             owner = mods[i][0] if i >= 0 else ""
             if name not in labels:
                 labels[name] = op_label(name)
-            label = (role.get(owner) or program_name(owner)) + ": " + \
-                labels[name]
+            program = role.get(owner) or program_name(owner)
+            if path:
+                program += "/" + scope_of(path[0])
+            label = program + ": " + labels[name]
             ops[label] = ops.get(label, 0.0) + (b - a) * 1e-9
         edges = [lo] + [x for iv in busy for x in iv] + [hi]
         for a, b in zip(edges[::2], edges[1::2]):
